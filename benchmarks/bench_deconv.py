@@ -506,8 +506,6 @@ def sharded_rows(devices: int = 8, stream=(5, 8, 19)):
     timings are a dispatch-count proxy, the structure (devices x
     per-shard tiles) is what carries over to TPU."""
     import os
-    import subprocess
-    import sys
     import textwrap
 
     import repro
@@ -541,6 +539,7 @@ def sharded_rows(devices: int = 8, stream=(5, 8, 19)):
             err = max(err, float(np.abs(eng.generate(z)
                                         - ref.generate(z)).max()))
         print(json.dumps({{
+            "platform": jax.devices()[0].platform,
             "devices": eng.n_devices,
             "buckets": list(eng.buckets),
             "stream": list({tuple(stream)}),
@@ -551,24 +550,31 @@ def sharded_rows(devices: int = 8, stream=(5, 8, 19)):
                             eng.throughput().items()}},
         }}))
     """)
+    return _run_child(code, src_dir)
+
+
+def _run_child(code: str, src_dir: str) -> dict:
+    """Run a bench child on forced host devices; its last stdout line is
+    the row.  A failed child raises with its stderr."""
+    import os
+    import subprocess
+    import sys
+
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         timeout=1800,
         env={**os.environ, "PYTHONPATH": src_dir},
     )
     if proc.returncode != 0:
-        return {"error": proc.stderr[-2000:]}
+        raise RuntimeError(f"bench child failed:\n{proc.stderr[-2000:]}")
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def print_sharded(row):
     if not row:
         return
-    print("# mesh-sharded bucket serving (MNIST generator, forced host "
-          "devices; per-shard autotuned tiles)")
-    if "error" in row:
-        print(f"sharded bench failed:\n{row['error']}")
-        return
+    print(f"# mesh-sharded bucket serving (MNIST generator, forced "
+          f"{row['platform']} devices; per-shard autotuned tiles)")
     tput = {k: f"{v['img_per_s']:.1f}" for k, v in row["throughput"].items()}
     print(f"devices={row['devices']} buckets={row['buckets']} "
           f"compiles={row['compiles']} padded={row['padded_images']} "
@@ -590,8 +596,6 @@ def degraded_rows(devices: int = 8, keep: int = 4, stream=(5, 8, 19),
     absolute img/s is a dispatch proxy, but the pre/post ratio and the
     recovery split (remesh vs first-request) carry over."""
     import os
-    import subprocess
-    import sys
     import textwrap
 
     import repro
@@ -650,6 +654,7 @@ def degraded_rows(devices: int = 8, keep: int = 4, stream=(5, 8, 19),
         post_img_s = run_stream({reps})
         post = {{str(k): v for k, v in eng.throughput().items()}}
         print(json.dumps({{
+            "platform": jax.devices()[0].platform,
             "devices_before": ev["devices_before"],
             "devices_after": ev["devices_after"],
             "buckets_before": buckets_before,
@@ -665,24 +670,15 @@ def degraded_rows(devices: int = 8, keep: int = 4, stream=(5, 8, 19),
             "retries": eng.fault_stats["retries"],
         }}))
     """)
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=1800,
-        env={**os.environ, "PYTHONPATH": src_dir},
-    )
-    if proc.returncode != 0:
-        return {"error": proc.stderr[-2000:]}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return _run_child(code, src_dir)
 
 
 def print_degraded(row):
     if not row:
         return
     print("# degraded-mode serving: elastic recovery after losing half the "
-          "mesh (forced host devices; img/s is a dispatch proxy)")
-    if "error" in row:
-        print(f"degraded bench failed:\n{row['error']}")
-        return
+          f"mesh (forced {row['platform']} devices; img/s is a dispatch "
+          "proxy)")
     matches = row["plan_hash_matches"]
     print(f"devices {row['devices_before']} -> {row['devices_after']}  "
           f"buckets {row['buckets_before']} -> {row['buckets_after']}")

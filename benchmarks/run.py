@@ -16,6 +16,9 @@ def main() -> None:
     smoke = "--smoke" in sys.argv
     reps = 10 if fast else 50
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import bench_deconv, bench_dse, bench_resource, bench_sparsity
 
     if smoke:

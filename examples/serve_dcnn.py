@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 import repro.workloads as workloads
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dcnn import generator_init
 from repro.serve import (AdmissionRejected, AsyncServeFrontend,
                          DcnnServeEngine, EngineConfig, TenantClass)
@@ -99,6 +100,7 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a Perfetto trace of the run to this path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace:
         from repro.obs import trace as obstrace

@@ -48,7 +48,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ...core.dse import TPU_V5E, Device
+from ...core.dse import TPU_V5E, Device, planning_device
 from ...core.tiling import kernel_vmem_bytes
 from .rules import CheckReport, PlanRuleViolation, Severity, rule
 
@@ -464,7 +464,7 @@ def check_schema(r, error: Exception,
 def check_network_plan(
     plan,
     *,
-    device: Device = TPU_V5E,
+    device: Optional[Device] = None,
     n_devices: int = 1,
     buckets: Optional[Sequence[int]] = None,
     params: Optional[Dict[str, Any]] = None,
@@ -476,6 +476,7 @@ def check_network_plan(
     and ``buckets`` enable the mesh-alignment rule (the serving engine
     passes its own); ``params`` enables the weights-vs-digest staleness
     check for pallas_sparse plans.  Nothing is executed or compiled."""
+    device = planning_device() if device is None else device
     report = CheckReport(name or f"plan-drc:{plan.name}")
     report.extend(check_backend(plan))
     report.extend(check_vmem_budget(plan, device))

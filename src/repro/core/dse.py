@@ -59,6 +59,29 @@ TPU_V5E = Device(
     int8_peak_ops=394e12,  # the MXU's doubled int8 rate
 )
 
+# Planning constants per TPU generation, keyed by `jax.Device.device_kind`.
+TPU_DEVICES = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def planning_device() -> Device:
+    """The `Device` whose VMEM budget and roofline plan this process's
+    kernels.  On a TPU it is looked up by ``device_kind``, and a kind
+    outside `TPU_DEVICES` raises rather than planning with another
+    chip's constants.  Off the chip (interpret mode, compile rehearsals)
+    the v5e stays the modelling target."""
+    import jax
+
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        return TPU_V5E
+    try:
+        return TPU_DEVICES[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no planning constants for TPU device kind {d.device_kind!r}; "
+            f"known kinds: {sorted(TPU_DEVICES)}") from None
+
+
 # The paper's PYNQ-Z2 point design: 16 CUs @ 125 MHz, 1 MAC/cycle/CU,
 # STREAM-measured DDR bandwidth on the PS-PL interface.
 PYNQ_Z2 = Device(

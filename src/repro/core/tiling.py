@@ -60,14 +60,15 @@ class HaloTile:
     the host pads ``left_halo`` rows on the left.  Consecutive windows
     overlap by ``extent - t_out/S`` halo rows; the kernel's per-tap slices
     inside the window are *static*: tap displacement ``d`` lives at local
-    row ``d - delta_min``.
+    row ``d + local_zero``.  An ``align``-ed window (`halo_tile`) starts
+    at an ``align`` multiple and spans a whole number of ``align`` rows.
     """
 
     t_out: int       # output tile extent (multiple of S)
     stride: int
     extent: int      # input window extent T_I (rows streamed per tile)
     base: int        # element offset of tile j's window: j*(t_out/S) + base
-    local_zero: int  # local row of displacement delta=0 == -delta_min
+    local_zero: int  # local row of displacement delta=0
 
     @property
     def step(self) -> int:
@@ -88,13 +89,21 @@ class HaloTile:
         return (n_tiles - 1) * self.step + self.base + self.extent
 
 
-def halo_tile(t_out: int, kernel: int, stride: int, padding: int) -> HaloTile:
+def halo_tile(t_out: int, kernel: int, stride: int, padding: int,
+              align: int = 1) -> HaloTile:
     """Input-window geometry for an S-aligned output tile (paper Eq. 5).
 
     The window extent equals ``exact_input_extent`` — the max-over-tiles
     input span — so the Pallas BlockSpec streams exactly the rows the tile
     touches (plus nothing), which is what drops per-tile HBM traffic from
     O(padded image) to O(T_I).
+
+    ``align`` is for the input's second-minor (W) dim, which Mosaic lays
+    out in ``SUBLANE``-row tiles: a DMA window there must start on a tile
+    boundary.  The window's base rounds down to an ``align`` multiple (the
+    dropped rows shift ``local_zero``) and its extent rounds up to whole
+    tiles; the caller keeps ``t_out / S`` an ``align`` multiple whenever
+    more than one window exists.
     """
     assert t_out % stride == 0, "tiles must be stride-aligned"
     plan = make_phase_plan(kernel, stride, padding)
@@ -103,13 +112,33 @@ def halo_tile(t_out: int, kernel: int, stride: int, padding: int) -> HaloTile:
     # host pads left_halo = max(0, -delta_min) rows; window j then starts at
     # j*step + (left_halo + delta_min) = j*step + max(0, delta_min) >= 0.
     base = plan.left_halo + plan.delta_min
+    shift = base % align
     return HaloTile(
         t_out=t_out,
         stride=stride,
-        extent=extent,
-        base=base,
-        local_zero=-plan.delta_min,
+        extent=_round_up(extent + shift, align),
+        base=base - shift,
+        local_zero=shift - plan.delta_min,
     )
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+# Mosaic lays the last two dims of every VMEM block out in (sublane, lane)
+# tiles of (SUBLANE * 4 // itemsize, LANE) elements: 8x128 for 32-bit data,
+# 32x128 for int8.
+SUBLANE = 8
+LANE = 128
+
+
+def tiled_bytes(shape: Tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes of a block once its last two dims are padded to whole
+    (sublane, lane) tiles — an 8-channel f32 block occupies 128 lanes."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * _round_up(sub, SUBLANE * 4 // itemsize)
+            * _round_up(lane, LANE) * itemsize)
 
 
 def kernel_vmem_bytes(
@@ -122,27 +151,35 @@ def kernel_vmem_bytes(
     t_n: int = 1,
     out_dtype_bytes: Optional[int] = None,
 ) -> int:
-    """Precise VMEM footprint of the halo-streaming Pallas kernel.
+    """VMEM footprint of the halo-streaming Pallas kernel, as Mosaic lays
+    it out.
 
-    Input/weight/bias blocks are double-buffered by the Mosaic pipeline
-    (x2); the 4-byte accumulator scratch (f32 for the dense/sparse
-    kernels, int32 for the int8 kernel) and the output block are single.
+    Every block is counted in whole (sublane, lane) tiles (`tiled_bytes`).
+    Input, weight, epilogue-vector and output blocks are double-buffered
+    by the Pallas pipeline (x2); the 4-byte accumulator scratch (f32 for
+    the dense/sparse kernels, int32 for the int8 kernel) is single, and
+    one phase's partial sum plus one tap slice live as in-kernel values.
     ``t_n`` is the batch tile: each grid program owns ``t_n`` images' halo
     windows / output blocks (the weight slab is batch-stationary).
     ``dtype_bytes`` is the streamed element width (1 for the int8 kernel);
     ``out_dtype_bytes`` overrides the output block's width when it differs
     from the inputs' (an int8 layer whose epilogue emits f32)."""
-    ht_h = halo_tile(t_oh, geom.kernel, geom.stride, geom.padding)
-    ht_w = halo_tile(t_ow, geom.kernel, geom.stride, geom.padding)
+    k, s = geom.kernel, geom.stride
+    ht_h = halo_tile(t_oh, k, s, geom.padding)
+    ht_w = halo_tile(t_ow, k, s, geom.padding, align=SUBLANE)
     out_b = dtype_bytes if out_dtype_bytes is None else out_dtype_bytes
-    x_bytes = t_n * ht_h.extent * ht_w.extent * t_ci * dtype_bytes
-    w_bytes = geom.kernel * geom.kernel * t_ci * t_co * dtype_bytes
-    # epilogue vectors stream as f32: bias for the float kernels, bias AND
-    # the per-channel requant scale for the int8 kernel (two in_specs)
-    b_bytes = (2 if dtype_bytes == 1 else 1) * t_co * max(dtype_bytes, 4)
-    y_bytes = t_n * t_oh * t_ow * t_co * out_b
-    acc_bytes = t_n * t_oh * t_ow * t_co * 4
-    return 2 * (x_bytes + w_bytes + b_bytes) + y_bytes + acc_bytes
+    x_bytes = tiled_bytes((t_n, ht_h.extent, ht_w.extent, t_ci), dtype_bytes)
+    w_bytes = tiled_bytes((k, k, t_ci, t_co), dtype_bytes)
+    # epilogue vectors stream as f32 (1, T_CO) rows: bias for the float
+    # kernels, bias AND the per-channel requant scale for the int8 kernel
+    v_bytes = (2 if dtype_bytes == 1 else 1) * tiled_bytes((1, t_co), 4)
+    y_bytes = tiled_bytes((t_n, t_oh, t_ow, t_co), out_b)
+    acc_bytes = tiled_bytes((t_n, t_oh, t_ow, t_co), 4)
+    rows = t_n * (t_oh // s) * (t_ow // s)
+    tmp_bytes = (tiled_bytes((rows, t_co), 4)
+                 + tiled_bytes((rows, t_ci), dtype_bytes))
+    return (2 * (x_bytes + w_bytes + v_bytes + y_bytes) + acc_bytes
+            + tmp_bytes)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,7 +13,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def microbatch(x: jax.Array, n_micro: int) -> jax.Array:
@@ -47,7 +47,14 @@ def pipeline_apply(
             return buf
         spec = P(axis, *([None] * (buf.ndim - 1)))
         return jax.lax.with_sharding_constraint(
-            buf, NamedSharding(mesh, spec))
+            buf, NamedSharding(auto_mesh, spec))
+
+    # the schedule is a layout hint the compiler propagates through the
+    # shifts, which only Auto axes take (`jax.make_mesh` defaults to
+    # Explicit ones): view the same devices with every axis Auto
+    auto_mesh = None if mesh is None else Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
     buf = shard_stages(jnp.zeros((n_stages,) + mb_shape, xm.dtype))
     outs = []

@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.dse import TPU_V5E, Device, tile_attainable
-from ..core.tiling import DeconvGeometry, kernel_vmem_bytes
+from ..core.dse import TPU_V5E, Device, planning_device, tile_attainable
+from ..core.tiling import LANE, SUBLANE, DeconvGeometry, kernel_vmem_bytes
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 # v2: the batch tile t_n joined the schema — both the key format and the
@@ -48,7 +48,10 @@ _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 # never be forgotten from the key and silently alias two requests again.
 # v3 keys, which did hand-assemble, are dropped on load like every other
 # stale schema.
-_CACHE_VERSION = 4
+# v5: candidates are only tiles Mosaic can compile (sublane-aligned W
+# windows, full-dim or lane-multiple channel tiles, t_co <= 128), so a v4
+# entry may name a tile the chip's compiler refuses.
+_CACHE_VERSION = 5
 _lock = threading.Lock()
 _cache: Optional[Dict[str, dict]] = None
 
@@ -90,7 +93,7 @@ def cache_path() -> pathlib.Path:
 def cache_key(geom: DeconvGeometry, dtype, backend: str,
               device: Device = TPU_V5E, batch: int = 1,
               out_dtype_bytes: Optional[int] = None) -> str:
-    """v4 cache key: a `DeconvPlan` content hash over the tile-planning
+    """Cache key: a `DeconvPlan` content hash over the tile-planning
     inputs (geometry, dtype, batch, backend, epilogue output width).
 
     The platform and the modeled device stay in the readable prefix:
@@ -170,11 +173,37 @@ def clear_cache() -> None:
 # ---------------------------------------------------------------------------
 # candidate enumeration + model ranking
 # ---------------------------------------------------------------------------
-def _channel_tile_options(c: int) -> List[int]:
-    """Channel-tile candidates: lane-width multiples clamped to the padded
-    channel count (the kernel pads channels up to the tile)."""
-    cp = _round_up(c, 8)
-    return sorted({min(cp, v) for v in (32, 64, 128)})
+def _channel_tile_options(c: int, dtype_bytes: int = 4) -> List[int]:
+    """Input-channel tile candidates Mosaic can block.  A channel count
+    within one lane width streams whole (the block spans the padded dim);
+    a wider one takes lane-width multiples up to its lane-padded count
+    (the kernel pads channels up to the tile).  The int8 kernel always
+    takes lane multiples: its window's (rows, channels) reshape is a plain
+    layout change only over whole 128-lane int8 rows."""
+    if c <= LANE and dtype_bytes != 1:
+        return [_round_up(c, SUBLANE)]
+    cp = _round_up(c, LANE)
+    return [v for v in (LANE, 2 * LANE, 4 * LANE) if v <= cp]
+
+
+def _out_channel_tile(c: int) -> int:
+    """The output-channel tile: the whole (sublane-padded) channel count
+    within one lane width, else one lane width — the accumulator's strided
+    phase store takes no wider a last dim."""
+    return min(_round_up(c, SUBLANE), LANE)
+
+
+def _spatial_tile_options(out: int, stride: int,
+                          max_spatial: int) -> List[int]:
+    """Square spatial-tile candidates Mosaic can window, ascending: the
+    single tile over the whole (stride-padded) output, plus every smaller
+    multiple of ``SUBLANE * stride`` up to ``max_spatial`` — there each W
+    window advances ``t / S`` input rows, a whole number of sublanes, so
+    every window starts on a sublane boundary."""
+    full = _round_up(out, stride)
+    step = SUBLANE * stride
+    return sorted({full} | set(range(step, min(full, max_spatial + 1),
+                                     step)))
 
 
 def _batch_tile_options(batch: int, cap: int = 64) -> List[int]:
@@ -199,28 +228,25 @@ def legal_tile_candidates(
     batch: int = 1,
     out_dtype_bytes: Optional[int] = None,
 ) -> List[Tuple[int, int, int, int, int]]:
-    """All (t_oh, t_ow, t_ci, t_co, t_n) with stride-aligned square spatial
-    tiles that fit the on-chip budget (paper Fig. 5 'legal solutions'),
-    jointly enumerated with the batch tile.  ``out_dtype_bytes`` prices a
-    wider output block than the streamed dtype (the last int8 layer's f32
-    epilogue) so near-budget candidates don't pass the filter at a
-    quarter of their real output footprint."""
-    s = geom.stride
-    oh_cap = _round_up(min(geom.out_h, max_spatial), s)
-    spatial = list(range(s, oh_cap + 1, s))
-    # the full-output tile (single spatial program) is always a candidate,
-    # even beyond max_spatial — the VMEM filter below still applies
-    spatial.append(_round_up(geom.out_h, s))
+    """All (t_oh, t_ow, t_ci, t_co, t_n) with square spatial tiles Mosaic
+    can window (`_spatial_tile_options`) and channel tiles it can block
+    (`_channel_tile_options`, `_out_channel_tile`) that fit the on-chip
+    budget (paper Fig. 5 'legal solutions'), jointly enumerated with the
+    batch tile.  The full-output tile is a candidate even beyond
+    ``max_spatial``.  ``out_dtype_bytes`` prices a wider output block
+    than the streamed dtype (the last int8 layer's f32 epilogue) so
+    near-budget candidates don't pass the filter at a quarter of their
+    real output footprint."""
+    t_co = _out_channel_tile(geom.c_out)
     out: List[Tuple[int, int, int, int, int]] = []
-    for t in sorted(set(spatial)):
-        for t_ci in _channel_tile_options(geom.c_in):
-            for t_co in _channel_tile_options(geom.c_out):
-                for t_n in _batch_tile_options(batch):
-                    fp = kernel_vmem_bytes(geom, t, t, t_ci, t_co,
-                                           dtype_bytes, t_n=t_n,
-                                           out_dtype_bytes=out_dtype_bytes)
-                    if fp <= vmem_budget:
-                        out.append((t, t, t_ci, t_co, t_n))
+    for t in _spatial_tile_options(geom.out_h, geom.stride, max_spatial):
+        for t_ci in _channel_tile_options(geom.c_in, dtype_bytes):
+            for t_n in _batch_tile_options(batch):
+                fp = kernel_vmem_bytes(geom, t, t, t_ci, t_co, dtype_bytes,
+                                       t_n=t_n,
+                                       out_dtype_bytes=out_dtype_bytes)
+                if fp <= vmem_budget:
+                    out.append((t, t, t_ci, t_co, t_n))
     return out
 
 
@@ -263,45 +289,34 @@ def fallback_tiles(
     batch: int = 1,
     out_dtype_bytes: Optional[int] = None,
 ) -> TileChoice:
-    """The old fixed heuristic (~32x32 spatial, 128-channel tiles), now
-    clamped through `kernel_vmem_bytes` so large CI x CO layers can no
-    longer blow the VMEM budget: shrink channels first (halving), then the
-    spatial tile, until the footprint fits.  The batch tile grows (powers
-    of two, within the batch and the budget) until the tap matmuls reach
-    ~128 contraction rows — a full MXU column load."""
+    """The fixed heuristic (~32x32 spatial, the narrowest legal channel
+    tiles), clamped through `kernel_vmem_bytes` so large CI x CO layers
+    can no longer blow the VMEM budget: the spatial tile shrinks through
+    the Mosaic-legal sizes until the footprint fits.  The batch tile then
+    grows (powers of two, within the batch and the budget) until the tap
+    matmuls reach ~128 contraction rows — a full MXU column load."""
     s = geom.stride
-    t_oh = min(_round_up(geom.out_h, s), _round_up(32, s))
-    t_ow = min(_round_up(geom.out_w, s), _round_up(32, s))
-    t_ci = min(_round_up(geom.c_in, 8), 128)
-    t_co = min(_round_up(geom.c_out, 8), 128)
+    spatial = _spatial_tile_options(geom.out_h, s, max_spatial=32)
+    # largest legal tile <= 32 (the smallest when even that is larger)
+    spatial = [t for t in spatial if t <= 32] or spatial[:1]
+    t_ci = _channel_tile_options(geom.c_in, dtype_bytes)[0]
+    t_co = _out_channel_tile(geom.c_out)
+
+    def vmem(t: int, tn: int) -> int:
+        return kernel_vmem_bytes(geom, t, t, t_ci, t_co, dtype_bytes,
+                                 t_n=tn, out_dtype_bytes=out_dtype_bytes)
+
+    while len(spatial) > 1 and vmem(spatial[-1], 1) > vmem_budget:
+        spatial.pop()
+    t = spatial[-1]
     t_n = 1
-
-    def fits(tn=None) -> bool:
-        return kernel_vmem_bytes(
-            geom, t_oh, t_ow, t_ci, t_co, dtype_bytes,
-            t_n=(t_n if tn is None else tn),
-            out_dtype_bytes=out_dtype_bytes) <= vmem_budget
-
-    while not fits():
-        if t_ci > 8:
-            t_ci = max(8, t_ci // 2)
-        elif t_co > 8:
-            t_co = max(8, t_co // 2)
-        elif t_oh > s or t_ow > s:
-            t_oh = max(s, _round_up(t_oh // 2, s))
-            t_ow = max(s, _round_up(t_ow // 2, s))
-        else:
-            break  # smallest legal tile; nothing left to shrink
-    rows_per_img = (t_oh // s) * (t_ow // s)
+    rows_per_img = (t // s) ** 2
     while (t_n * 2 <= batch and t_n * rows_per_img < 128
-           and fits(tn=t_n * 2)):
+           and vmem(t, t_n * 2) <= vmem_budget):
         t_n *= 2
     return TileChoice(
-        t_oh=t_oh, t_ow=t_ow, t_ci=t_ci, t_co=t_co, t_n=t_n,
-        source="fallback",
-        vmem_bytes=kernel_vmem_bytes(geom, t_oh, t_ow, t_ci, t_co,
-                                     dtype_bytes, t_n=t_n,
-                                     out_dtype_bytes=out_dtype_bytes),
+        t_oh=t, t_ow=t, t_ci=t_ci, t_co=t_co, t_n=t_n,
+        source="fallback", vmem_bytes=vmem(t, t_n),
     )
 
 
@@ -312,7 +327,7 @@ def network_tiles(
     batch: int = 1,
     refine: bool = False,
     autotune: bool = True,
-    device: Device = TPU_V5E,
+    device: Optional[Device] = None,
 ) -> Optional[Dict[int, TileChoice]]:
     """Per-layer tile choices for a whole generator network.
 
@@ -327,6 +342,7 @@ def network_tiles(
     images while every intermediate layer re-quantizes to int8."""
     if backend not in ("pallas", "pallas_sparse"):
         return None
+    device = planning_device() if device is None else device
     if dtype is None:
         dtype = cfg.jdtype
     geoms = list(cfg.geometries())
@@ -403,13 +419,38 @@ def _time_candidate(
     return float(np.median(ts))
 
 
+def _refine(geom: DeconvGeometry, ranked: List[TileChoice], dtype,
+            backend: str, batch: int) -> TileChoice:
+    """Time each candidate and return the fastest, ``source="timed"``."""
+    from ..obs import metrics as obsmetrics
+
+    failures = obsmetrics.default_registry().counter(
+        "autotune.refine_failures",
+        "refine candidates the compiler refused (label: candidate)")
+    timed = []
+    errors = []
+    for c in ranked:
+        try:
+            timed.append((_time_candidate(geom, c, dtype, backend,
+                                          batch=max(batch, c.t_n)), c))
+        except jax.errors.JaxRuntimeError as e:
+            failures.inc(backend=backend, candidate=str(c.as_kwargs()))
+            errors.append((c.as_kwargs(), e))
+    if not timed:
+        raise RuntimeError(
+            f"every refine candidate for {geom} failed to compile: "
+            f"{[kw for kw, _ in errors]}") from errors[-1][1]
+    return dataclasses.replace(min(timed, key=lambda tc: tc[0])[1],
+                               source="timed")
+
+
 def choose_tiles(
     geom: DeconvGeometry,
     dtype=jnp.float32,
     backend: str = "pallas",
     refine: bool = False,
     refine_top_k: int = 3,
-    device: Device = TPU_V5E,
+    device: Optional[Device] = None,
     use_cache: bool = True,
     batch: int = 1,
     out_dtype_bytes: Optional[int] = None,
@@ -424,7 +465,13 @@ def choose_tiles(
     timing cost is paid once per (geometry, dtype, backend, batch)).
     ``out_dtype_bytes`` widens the modeled output block when the kernel's
     epilogue emits a wider dtype than it streams (the last int8 layer
-    writes f32 images)."""
+    writes f32 images).  ``device`` defaults to `dse.planning_device()`:
+    the attached TPU's constants, or the v5e off the chip.
+
+    A refine candidate that the compiler refuses is counted
+    (``autotune.refine_failures``, labelled with the candidate) and
+    skipped; when every candidate is refused the call raises."""
+    device = planning_device() if device is None else device
     dtype_bytes = np.dtype(dtype).itemsize
     if refine and np.dtype(dtype).kind != "f":
         # the timing harness drives the float kernels with random normal
@@ -456,17 +503,8 @@ def choose_tiles(
                                  out_dtype_bytes=out_dtype_bytes)
         choice = ranked[0]
         if refine:
-            timed = []
-            for c in ranked[:refine_top_k]:
-                try:
-                    timed.append((_time_candidate(geom, c, dtype, backend,
-                                                  batch=max(batch, c.t_n)),
-                                  c))
-                except Exception:  # a candidate may fail to lower; skip it
-                    continue
-            if timed:
-                choice = dataclasses.replace(
-                    min(timed, key=lambda tc: tc[0])[1], source="timed")
+            choice = _refine(geom, ranked[:refine_top_k], dtype, backend,
+                             batch)
     if use_cache:
         _store(key, choice)
     return choice

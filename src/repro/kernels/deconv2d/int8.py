@@ -34,9 +34,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.offsets import PhasePlan, make_phase_plan
-from ...core.tiling import HaloTile, halo_tile
+from ...core.tiling import SUBLANE, HaloTile, halo_tile
 from ...quant.qmath import QMAX, quantize_symmetric
-from .kernel import COMPILER_PARAMS, apply_activation, x_halo_blockspec
+from .kernel import (COMPILER_PARAMS, apply_activation, check_mosaic_tiles,
+                     x_halo_blockspec)
 
 
 def requant_epilogue(acc_i32: jax.Array, scale: jax.Array, bias: jax.Array,
@@ -60,7 +61,7 @@ def _deconv2d_int8_kernel(
     s_ref,      # (1, T_CO)                VMEM f32 combined s_x * s_w
     b_ref,      # (1, T_CO)                VMEM f32 bias
     o_ref,      # (T_N, T_OH, T_OW, T_CO)  VMEM int8 or f32
-    acc_ref,    # (T_N, T_OH/S, S, T_OW/S, S, T_CO) int32 scratch
+    acc_ref,    # (T_N, T_OH/S, S, T_OW, T_CO) int32 scratch
     *,
     plan: PhasePlan,
     ht_h: HaloTile,
@@ -97,7 +98,8 @@ def _deconv2d_int8_kernel(
                         w_ref[kh, kw],
                         preferred_element_type=jnp.int32,
                     )
-            acc_ref[:, :, ph, :, pw, :] += acc.reshape(t_n, th, tw, t_co)
+            acc_ref[:, :, ph, pl.ds(pw, tw, stride=s), :] += acc.reshape(
+                t_n, th, tw, t_co)
 
     @pl.when(ci_idx == n_ci_tiles - 1)
     def _flush():
@@ -133,12 +135,14 @@ def deconv2d_int8_pallas_call(
     assert cip % t_ci == 0 and cop % t_co == 0
     assert n % t_n == 0, "batch must be padded to a t_n multiple"
     ht_h = halo_tile(t_oh, k, s, plan.padding)
-    ht_w = halo_tile(t_ow, k, s, plan.padding)
+    ht_w = halo_tile(t_ow, k, s, plan.padding, align=SUBLANE)
     n_tiles_h = ohp // t_oh
     n_tiles_w = owp // t_ow
     assert ihp >= ht_h.min_padded_extent(n_tiles_h), "input under-padded (h)"
     assert iwp >= ht_w.min_padded_extent(n_tiles_w), "input under-padded (w)"
     n_ci = cip // t_ci
+    if not interpret:
+        check_mosaic_tiles(ht_w, n_tiles_w, t_ci, cip, t_co, cop, int8=True)
     grid = (n // t_n, n_tiles_h, n_tiles_w, cop // t_co, n_ci)
     out_dtype = jnp.int8 if out_scale is not None else jnp.float32
 
@@ -157,7 +161,7 @@ def deconv2d_int8_pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            x_halo_blockspec(ht_h, ht_w, t_ci, t_n),
+            x_halo_blockspec(ht_h, ht_w, t_ci, t_n, n_tiles_w, n_ci),
             pl.BlockSpec(
                 (k, k, t_ci, t_co),
                 lambda nb, oh, ow, co, ci: (0, 0, ci, co),
@@ -171,13 +175,9 @@ def deconv2d_int8_pallas_call(
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohp, owp, cop), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((t_n, t_oh // s, s, t_ow // s, s, t_co), jnp.int32)
+            pltpu.VMEM((t_n, t_oh // s, s, t_ow, t_co), jnp.int32)
         ],
-        compiler_params=COMPILER_PARAMS(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="deconv2d_int8_halo_reverse_loop",
     )(x_padded, w, scale, b)

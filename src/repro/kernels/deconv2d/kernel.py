@@ -12,13 +12,14 @@ This is the paper's FPGA accelerator re-derived for the TPU memory hierarchy:
   array, and it amortizes the weight-slab HBM stream over T_N images.
 * **Eq. 5 input streaming**: the x BlockSpec is a per-output-tile *halo
   window* of constant extent ``T_IH x T_IW`` (core.tiling.halo_tile) whose
-  unblocked index map follows the output grid — each program streams only
-  the input rows its tile touches (overlapping halo reads), never the whole
-  image.  HBM traffic per tile is O(T_IH*T_IW), independent of image size.
+  element-offset index map follows the output grid — each program streams
+  only the input rows its tile touches (overlapping halo reads), never the
+  whole image.  HBM traffic per tile is O(T_IH*T_IW), independent of image
+  size.  The W window is sublane-aligned (`x_halo_blockspec`).
 * **Eq. 3 offsets → trace-time phase plan**: the stride-hole-skipping offsets
   are folded into a static (phase → taps, input displacement) table computed
   on the host; inside the halo window every tap slice is *static* (local row
-  ``delta - delta_min``) — the kernel body contains zero modulo/division ops
+  ``HaloTile.local_offset(delta)``) — the kernel body contains zero modulo/division ops
   and zero grid-dependent address arithmetic.
 * **Enhancement (2) — loop interchange**: the K×K tap loops are the outermost
   static loops; each (tap, phase) contribution is a channel-contraction
@@ -28,8 +29,10 @@ This is the paper's FPGA accelerator re-derived for the TPU memory hierarchy:
   phase on the f32 accumulator — the generator never materializes a
   pre-activation layer in HBM.
 
-The accumulator scratch is laid out ``(T_OH/S, S, T_OW/S, S, T_CO)`` so the
-final phase reassembly is a pure reshape (no transpose).
+The accumulator scratch is laid out ``(T_N, T_OH/S, S, T_OW, T_CO)``: phase
+``(ph, pw)`` accumulates into H-phase slot ``ph`` and every S-th W row from
+``pw`` (a strided 32-bit store), so the final phase reassembly is a
+leading-dim reshape and the block keeps the output's (T_OW, T_CO) tiling.
 """
 from __future__ import annotations
 
@@ -41,14 +44,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.dse import TPU_V5E
 from ...core.offsets import PhasePlan
-from ...core.tiling import HaloTile, halo_tile
-
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from ...core.tiling import LANE, SUBLANE, HaloTile, halo_tile
 
 ACTIVATIONS = (None, "none", "relu", "tanh")
+
+# Shared by the dense, int8 and sparse kernels: grid axes (batch, oh, ow,
+# co, ci) with the CI reduction last, and a scoped-VMEM limit twice the
+# 16 MiB `kernel_vmem_bytes` fits blocks into.  The model counts blocks,
+# scratch and one tap's values; the headroom covers the relayout copies
+# Mosaic makes of sublane-unaligned tap slices, which it does not count
+# (an int8 K=7 layer at t_n=64 overran a 16 MiB limit by 292 KiB).  A
+# v5e core has 128 MiB of VMEM.
+COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=(
+        "parallel", "parallel", "parallel", "parallel", "arbitrary",
+    ),
+    vmem_limit_bytes=2 * TPU_V5E.onchip_bytes,
+)
 
 
 def apply_activation(y: jax.Array, activation: Optional[str]) -> jax.Array:
@@ -63,30 +77,64 @@ def apply_activation(y: jax.Array, activation: Optional[str]) -> jax.Array:
     return y
 
 
+def window_start(idx, step: int, base: int, n_tiles: int):
+    """Element offset of grid index ``idx``'s window: ``idx*step + base``.
+    A dim with a single window returns the constant ``base`` — Mosaic must
+    prove a sublane/lane window offset tile-aligned, and it cannot do so
+    from ``idx * step`` when ``step`` is not a tile multiple."""
+    return base if n_tiles == 1 else idx * step + base
+
+
 def x_halo_blockspec(
-    ht_h: HaloTile, ht_w: HaloTile, t_ci: int, t_n: int = 1
+    ht_h: HaloTile, ht_w: HaloTile, t_ci: int, t_n: int, n_tiles_w: int,
+    n_ci: int,
 ) -> pl.BlockSpec:
     """Per-output-tile input window BlockSpec (the Eq. 5 streaming read).
 
-    Unblocked indexing: the index map returns *element* offsets, which is
-    what lets consecutive output tiles read overlapping halo windows —
-    impossible with block-granular indexing.  The leading dimension is the
-    batch tile: one program streams the windows of ``t_n`` images (batch
-    folded into the MXU row dimension).  Exposed as a function so the
+    Every block dim is a `pl.Element`, so the index map returns *element*
+    offsets, which is what lets consecutive output tiles read overlapping
+    halo windows — impossible with block-granular indexing.  The leading
+    dimension is the batch tile: one program streams the windows of
+    ``t_n`` images (batch folded into the MXU row dimension).  ``ht_w``
+    must be sublane-aligned (``halo_tile(..., align=SUBLANE)``) and
+    ``t_ci`` a lane multiple, unless the W / channel dim has a single
+    window (``n_tiles_w`` / ``n_ci`` == 1).  Exposed as a function so the
     tests can assert the block shape / index map directly.
     """
-    step_h, base_h = ht_h.step, ht_h.base
-    step_w, base_w = ht_w.step, ht_w.base
-
     def index_map(nb, oh, ow, co, ci):
-        return (nb * t_n, oh * step_h + base_h, ow * step_w + base_w,
-                ci * t_ci)
+        return (nb * t_n, oh * ht_h.step + ht_h.base,
+                window_start(ow, ht_w.step, ht_w.base, n_tiles_w),
+                window_start(ci, t_ci, 0, n_ci))
 
     return pl.BlockSpec(
-        (t_n, ht_h.extent, ht_w.extent, t_ci),
+        (pl.Element(t_n), pl.Element(ht_h.extent), pl.Element(ht_w.extent),
+         pl.Element(t_ci)),
         index_map,
-        indexing_mode=pl.unblocked,
     )
+
+
+def check_mosaic_tiles(ht_w: HaloTile, n_tiles_w: int, t_ci: int, cip: int,
+                       t_co: int, cop: int, int8: bool = False) -> None:
+    """The block-shape rules Mosaic enforces and interpret mode does not:
+    a W window offset on a sublane boundary; an input-channel block that
+    is a lane multiple or spans the padded dim (always a lane multiple for
+    ``int8``, whose window reshape needs whole 128-lane rows); and an
+    output-channel block of at most one lane width, which the
+    accumulator's strided phase store needs, that is a lane or spans the
+    padded dim.  Called for compiled (non-interpret) kernels, so an
+    illegal tile fails with the rule it broke before Mosaic's message."""
+    bad = []
+    if n_tiles_w > 1 and ht_w.step % SUBLANE:
+        bad.append(f"t_ow/S={ht_w.step} is not a multiple of {SUBLANE} "
+                   "with more than one W tile")
+    if t_ci % LANE and (int8 or t_ci != cip):
+        bad.append(f"t_ci={t_ci} is not a multiple of {LANE}"
+                   + ("" if int8 else f" nor the padded channel count {cip}"))
+    if t_co > LANE or (t_co != LANE and t_co != cop):
+        bad.append(f"t_co={t_co} is neither {LANE} nor the padded channel "
+                   f"count {cop} <= {LANE}")
+    if bad:
+        raise ValueError("tiles Mosaic cannot block: " + "; ".join(bad))
 
 
 def _deconv2d_kernel(
@@ -94,7 +142,7 @@ def _deconv2d_kernel(
     w_ref,      # (K, K, T_CI, T_CO)       VMEM (batch-stationary)
     b_ref,      # (1, T_CO)                VMEM
     o_ref,      # (T_N, T_OH, T_OW, T_CO)  VMEM
-    acc_ref,    # (T_N, T_OH/S, S, T_OW/S, S, T_CO) f32 scratch
+    acc_ref,    # (T_N, T_OH/S, S, T_OW, T_CO) f32 scratch
     *,
     plan: PhasePlan,
     ht_h: HaloTile,
@@ -137,11 +185,12 @@ def _deconv2d_kernel(
                         w_ref[kh, kw],
                         preferred_element_type=jnp.float32,
                     )
-            acc_ref[:, :, ph, :, pw, :] += acc.reshape(t_n, th, tw, t_co)
+            acc_ref[:, :, ph, pl.ds(pw, tw, stride=s), :] += acc.reshape(
+                t_n, th, tw, t_co)
 
     @pl.when(ci_idx == n_ci_tiles - 1)
     def _flush():
-        # One-shot disjoint write: reassemble phases, fused epilogue, cast.
+        # One-shot disjoint write: merge the H phases, fused epilogue, cast.
         y = acc_ref[...].reshape(t_n, t_oh, t_ow, t_co)
         o_ref[...] = apply_activation(y, activation).astype(out_dtype)
 
@@ -170,12 +219,14 @@ def deconv2d_pallas_call(
     assert cip % t_ci == 0 and cop % t_co == 0
     assert n % t_n == 0, "batch must be padded to a t_n multiple"
     ht_h = halo_tile(t_oh, k, s, plan.padding)
-    ht_w = halo_tile(t_ow, k, s, plan.padding)
+    ht_w = halo_tile(t_ow, k, s, plan.padding, align=SUBLANE)
     n_tiles_h = ohp // t_oh
     n_tiles_w = owp // t_ow
     assert ihp >= ht_h.min_padded_extent(n_tiles_h), "input under-padded (h)"
     assert iwp >= ht_w.min_padded_extent(n_tiles_w), "input under-padded (w)"
     n_ci = cip // t_ci
+    if not interpret:
+        check_mosaic_tiles(ht_w, n_tiles_w, t_ci, cip, t_co, cop)
     grid = (n // t_n, n_tiles_h, n_tiles_w, cop // t_co, n_ci)
 
     kernel = functools.partial(
@@ -193,7 +244,7 @@ def deconv2d_pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            x_halo_blockspec(ht_h, ht_w, t_ci, t_n),
+            x_halo_blockspec(ht_h, ht_w, t_ci, t_n, n_tiles_w, n_ci),
             pl.BlockSpec(
                 (k, k, t_ci, t_co),
                 lambda nb, oh, ow, co, ci: (0, 0, ci, co),
@@ -206,13 +257,9 @@ def deconv2d_pallas_call(
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohp, owp, cop), x_padded.dtype),
         scratch_shapes=[
-            pltpu.VMEM((t_n, t_oh // s, s, t_ow // s, s, t_co), jnp.float32)
+            pltpu.VMEM((t_n, t_oh // s, s, t_ow, t_co), jnp.float32)
         ],
-        compiler_params=COMPILER_PARAMS(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="deconv2d_halo_reverse_loop",
     )(x_padded, w, b)

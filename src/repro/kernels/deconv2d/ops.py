@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.offsets import make_phase_plan
-from ...core.tiling import DeconvGeometry, out_size
+from ...core.tiling import SUBLANE, DeconvGeometry, halo_tile, out_size
 from .kernel import deconv2d_pallas_call
 
 _warned_tile_kwargs = set()
@@ -82,25 +82,29 @@ def _round_up(x: int, m: int) -> int:
 def halo_pad_geometry(n: int, ih: int, iw: int, ci: int, co: int,
                       plan, t_oh: int, t_ow: int, t_ci: int, t_co: int,
                       t_n: int):
-    """Host-side padded geometry shared by the f32 and int8 jit wrappers.
+    """Host-side padded geometry shared by the dense, int8 and sparse jit
+    wrappers.
 
     Returns ``(oh, ow, ohp, owp, pad_l, pad_rh, pad_rw, cip, cop, t_n,
     np_)``: the true output extents, the tile-multiple output grid, the
     halo padding that keeps every per-tile window in bounds (enhancement
     3: all address arithmetic resolved ahead of the kernel), the channel
     tiles' padded extents, the batch tile clamped to the batch, and the
-    t_n-multiple padded batch.  One implementation, two kernels — the
-    padded geometry (and the final un-padding slice) can never drift
-    between the precisions."""
-    oh = out_size(ih, plan.kernel_size, plan.stride, plan.padding)
-    ow = out_size(iw, plan.kernel_size, plan.stride, plan.padding)
+    t_n-multiple padded batch.  The right padding covers the last window
+    of each dim, the W one sublane-aligned as the kernels stream it.  One
+    implementation, three kernels — the padded geometry (and the final
+    un-padding slice) can never drift between them."""
+    k, s, p = plan.kernel_size, plan.stride, plan.padding
+    oh = out_size(ih, k, s, p)
+    ow = out_size(iw, k, s, p)
     ohp = _round_up(oh, t_oh)
     owp = _round_up(ow, t_ow)
-    n_h_pad = ohp // plan.stride
-    n_w_pad = owp // plan.stride
     pad_l = plan.left_halo
-    pad_rh = max(0, (n_h_pad - 1 + plan.delta_max) - (ih - 1))
-    pad_rw = max(0, (n_w_pad - 1 + plan.delta_max) - (iw - 1))
+    need_h = halo_tile(t_oh, k, s, p).min_padded_extent(ohp // t_oh)
+    need_w = halo_tile(t_ow, k, s, p, align=SUBLANE).min_padded_extent(
+        owp // t_ow)
+    pad_rh = max(0, need_h - pad_l - ih)
+    pad_rw = max(0, need_w - pad_l - iw)
     cip = _round_up(ci, t_ci)
     cop = _round_up(co, t_co)
     t_n = min(t_n, n) if n > 0 else 1

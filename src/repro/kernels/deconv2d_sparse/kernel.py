@@ -27,8 +27,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.offsets import PhasePlan
-from ...core.tiling import HaloTile, halo_tile
-from ..deconv2d.kernel import COMPILER_PARAMS, apply_activation
+from ...core.tiling import SUBLANE, HaloTile, halo_tile
+from ..deconv2d.kernel import (COMPILER_PARAMS, apply_activation,
+                               check_mosaic_tiles, window_start)
 
 
 def build_schedule(block_tap_mask: np.ndarray):
@@ -64,7 +65,7 @@ def _sparse_kernel(
     w_ref,         # (K, K, T_CI, T_CO)
     b_ref,         # (1, T_CO)
     o_ref,         # (T_N, T_OH, T_OW, T_CO)
-    acc_ref,       # (T_N, T_OH/S, S, T_OW/S, S, T_CO) f32
+    acc_ref,       # (T_N, T_OH/S, S, T_OW, T_CO) f32
     *,
     plan: PhasePlan,
     ht_h: HaloTile,
@@ -114,7 +115,8 @@ def _sparse_kernel(
                             preferred_element_type=jnp.float32,
                         )
                         acc = acc + jnp.where(tap_live, contrib, 0.0)
-                acc_ref[:, :, ph, :, pw, :] += acc.reshape(t_n, th, tw, t_co)
+                acc_ref[:, :, ph, pl.ds(pw, tw, stride=s), :] += (
+                    acc.reshape(t_n, th, tw, t_co))
 
     @pl.when(l_idx == n_sched - 1)
     def _flush():
@@ -147,11 +149,14 @@ def deconv2d_sparse_pallas_call(
     s = plan.stride
     assert n % t_n == 0, "batch must be padded to a t_n multiple"
     ht_h = halo_tile(t_oh, k, s, plan.padding)
-    ht_w = halo_tile(t_ow, k, s, plan.padding)
+    ht_w = halo_tile(t_ow, k, s, plan.padding, align=SUBLANE)
     n_tiles_h = ohp // t_oh
     n_tiles_w = owp // t_ow
     assert ihp >= ht_h.min_padded_extent(n_tiles_h), "input under-padded (h)"
     assert iwp >= ht_w.min_padded_extent(n_tiles_w), "input under-padded (w)"
+    n_ci = cip // t_ci
+    if not interpret:
+        check_mosaic_tiles(ht_w, n_tiles_w, t_ci, cip, t_co, cop)
     n_sched = ci_idx.shape[1]
     grid = (n // t_n, n_tiles_h, n_tiles_w, cop // t_co, n_sched)
 
@@ -167,22 +172,21 @@ def deconv2d_sparse_pallas_call(
         activation=activation,
         out_dtype=x_padded.dtype,
     )
-    step_h, base_h = ht_h.step, ht_h.base
-    step_w, base_w = ht_w.step, ht_w.base
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (t_n, ht_h.extent, ht_w.extent, t_ci),
+                (pl.Element(t_n), pl.Element(ht_h.extent),
+                 pl.Element(ht_w.extent), pl.Element(t_ci)),
                 # Eq. 5 halo windows (t_n images) following the output grid,
                 # with DMA indirection on channels: only surviving CI slabs
                 # stream.
                 lambda nb, oh, ow, co, l, ci_idx, valid, taps: (
-                    nb * t_n, oh * step_h + base_h, ow * step_w + base_w,
-                    ci_idx[co, l] * t_ci,
+                    nb * t_n, oh * ht_h.step + ht_h.base,
+                    window_start(ow, ht_w.step, ht_w.base, n_tiles_w),
+                    window_start(ci_idx[co, l], t_ci, 0, n_ci),
                 ),
-                indexing_mode=pl.unblocked,
             ),
             pl.BlockSpec(
                 (k, k, t_ci, t_co),
@@ -200,19 +204,14 @@ def deconv2d_sparse_pallas_call(
             lambda nb, oh, ow, co, l, ci_idx, valid, taps: (nb, oh, ow, co),
         ),
         scratch_shapes=[
-            pltpu.VMEM((t_n, t_oh // plan.stride, plan.stride,
-                        t_ow // plan.stride, plan.stride, t_co), jnp.float32)
+            pltpu.VMEM((t_n, t_oh // s, s, t_ow, t_co), jnp.float32)
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, ohp, owp, cop), x_padded.dtype),
-        compiler_params=COMPILER_PARAMS(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="deconv2d_sparse_reverse_loop",
     )(ci_idx, valid, tap_mask, x_padded, w, b)

@@ -15,9 +15,8 @@ import numpy as np
 
 from ...core.offsets import make_phase_plan
 from ...core.sparsity import block_mask
-from ...core.tiling import out_size
-from ..deconv2d.ops import (_round_up, check_layer_plan, resolve_tiles,
-                            warn_legacy_tiles)
+from ..deconv2d.ops import (_round_up, check_layer_plan, halo_pad_geometry,
+                            resolve_tiles, warn_legacy_tiles)
 from .kernel import build_schedule, deconv2d_sparse_pallas_call
 
 
@@ -47,21 +46,10 @@ def _deconv2d_sparse_jit(
 ):
     n, ih, iw, ci = x.shape
     k, _, _, co = w.shape
-    s = stride
-    oh = out_size(ih, k, s, padding)
-    ow = out_size(iw, k, s, padding)
-    plan = make_phase_plan(k, s, padding)
-    ohp = _round_up(oh, t_oh)
-    owp = _round_up(ow, t_ow)
-    n_h_pad = ohp // s
-    n_w_pad = owp // s
-    pad_l = plan.left_halo
-    pad_rh = max(0, (n_h_pad - 1 + plan.delta_max) - (ih - 1))
-    pad_rw = max(0, (n_w_pad - 1 + plan.delta_max) - (iw - 1))
-    cip = _round_up(ci, t_ci)
-    cop = _round_up(co, t_co)
-    t_n = min(t_n, n) if n > 0 else 1
-    np_ = _round_up(n, t_n)
+    plan = make_phase_plan(k, stride, padding)
+    (oh, ow, ohp, owp, pad_l, pad_rh, pad_rw, cip, cop, t_n,
+     np_) = halo_pad_geometry(n, ih, iw, ci, co, plan, t_oh, t_ow, t_ci,
+                              t_co, t_n)
     xp = jnp.pad(x, ((0, np_ - n), (pad_l, pad_rh), (pad_l, pad_rw),
                      (0, cip - ci)))
     wp = jnp.pad(w, ((0, 0), (0, 0), (0, cip - ci), (0, cop - co)))

@@ -13,13 +13,10 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # axis_types / AxisType only exist on newer jax; Auto is the default
-    # behavior there, so omitting it is equivalent where it is missing.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the serving and training paths place arrays with
+    # shardings the compiler propagates, not with sharding-typed arrays
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

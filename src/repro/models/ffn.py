@@ -13,7 +13,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..dist.context import constrain, current
@@ -131,10 +130,10 @@ def moe_apply(
         return hb.at[gi, se_l, ps_l].set(upd, mode="drop")
 
     if use_sm:
-        hbuf = shard_map(
+        hbuf = jax.shard_map(
             _scatter_local, mesh=mesh,
             in_specs=(P(dp_axis), P(dp_axis), P(dp_axis), P(dp_axis)),
-            out_specs=P(dp_axis), check_rep=False,
+            out_specs=P(dp_axis), check_vma=False,
         )(xg, sorted_e, pos_safe, src_tok)
     else:
         hbuf = _scatter_local(xg, sorted_e, pos_safe, src_tok)
@@ -161,10 +160,10 @@ def moe_apply(
             (gat * ws_l[..., None]).astype(jnp.float32))
 
     if use_sm:
-        y = shard_map(
+        y = jax.shard_map(
             _combine_local, mesh=mesh,
             in_specs=(P(dp_axis),) * 5,
-            out_specs=P(dp_axis), check_rep=False,
+            out_specs=P(dp_axis), check_vma=False,
         )(out_e, sorted_e, pos_safe, src_tok, w_sorted)
     else:
         y = _combine_local(out_e, sorted_e, pos_safe, src_tok, w_sorted)

@@ -243,9 +243,9 @@ def build_layer_plan(
     the allocator and could serve another weight set's schedule).
     Non-tiled backends ("reverse_loop", "xla") get a plan with
     ``tiles=None``."""
-    from ..core.dse import TPU_V5E
+    from ..core.dse import planning_device
 
-    device = TPU_V5E if device is None else device
+    device = planning_device() if device is None else device
     dtype_name = np.dtype(dtype).name
     if backend not in ("pallas", "pallas_sparse"):
         return DeconvPlan(geometry=geom, batch=batch, dtype=dtype_name,

@@ -563,17 +563,16 @@ class DcnnServeEngine:
             if self.mesh is not None:
                 # SPMD: every device runs the same per-shard executable on
                 # its bucket/n_devices rows (the tiles above were fitted to
-                # exactly that sub-batch).  check_rep=False: pallas_call has
+                # exactly that sub-batch).  check_vma=False: pallas_call has
                 # no replication rule.
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
                 from ..dist.sharding import batch_pspec
 
                 baxes = self.rules.get("batch", "data")
-                apply = shard_map(apply, mesh=self.mesh,
-                                  in_specs=(P(), P(baxes)),
-                                  out_specs=P(baxes), check_rep=False)
+                apply = jax.shard_map(apply, mesh=self.mesh,
+                                      in_specs=(P(), P(baxes)),
+                                      out_specs=P(baxes), check_vma=False)
                 z_sh = NamedSharding(
                     self.mesh, batch_pspec(self.mesh, self.rules, bucket, 2))
                 img_sh = NamedSharding(

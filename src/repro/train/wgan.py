@@ -214,14 +214,13 @@ class WganTrainer:
         """shard_map (mesh) + jit + trace-count probe around a step body.
         ``n_batch_arg`` is the position of the batch-sharded argument."""
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             baxes = self.rules.get("batch", "data")
             n_in = body.__code__.co_argcount
             in_specs = tuple(P(baxes) if i == n_batch_arg else P()
                              for i in range(n_in))
-            body = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=P(), check_rep=False)
+            body = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False)
 
         def traced(*args):
             counts = self.trace_counts[kind]
@@ -229,6 +228,15 @@ class WganTrainer:
             return body(*args)
 
         return jax.jit(traced)
+
+    def _replicate(self, *trees):
+        """Params and optimizer states, replicated on the mesh (as is).
+        A step returns them so placed, and a step whose arguments change
+        placement between calls traces again."""
+        if self.mesh is None:
+            return trees
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(trees, NamedSharding(self.mesh, P()))
 
     def _psum(self, tree):
         baxes = self.rules.get("batch", "data")
@@ -334,6 +342,7 @@ class WganTrainer:
         if bucket not in self._critic_fns:
             self._critic_fns[bucket] = self._build_critic_fn(bucket)
         nv = jnp.asarray(n, jnp.int32)  # dynamic: no retrace per raggedness
+        dp, d_state, gp = self._replicate(dp, d_state, gp)
         return self._critic_fns[bucket](dp, d_state, gp, real, nv, key)
 
     def gen_step(self, gp, g_state, dp, key, batch: int):
@@ -343,6 +352,7 @@ class WganTrainer:
         bucket = self.bucket_for(int(batch))
         if bucket not in self._gen_fns:
             self._gen_fns[bucket] = self._build_gen_fn(bucket)
+        gp, g_state, dp = self._replicate(gp, g_state, dp)
         return self._gen_fns[bucket](gp, g_state, dp, key)
 
     @property

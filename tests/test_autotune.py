@@ -185,9 +185,9 @@ def test_v3_schema_keys_dropped_on_load(tmp_cache):
 
     from repro.kernels.autotune import _CACHE_VERSION, cache_key
 
-    assert _CACHE_VERSION == 4
+    assert _CACHE_VERSION == 5
     key4 = cache_key(MNIST_L2, jnp.float32, "pallas")
-    assert key4.startswith("v4|")
+    assert key4.startswith("v5|")
     # a v3-era key: hand-assembled readable tuple under the old version
     key3 = ("v3|cpu|tpu-v5e|pallas|float32|n1|i7x7|c256>128|k4s2p1")
     entry = {"t_oh": 2, "t_ow": 2, "t_ci": 8, "t_co": 8, "t_n": 1,
@@ -289,3 +289,64 @@ def test_autotuned_kernel_matches_reference(tmp_cache, rng):
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(deconv2d_ref(x, w, b, 2, 1)),
         rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dtype_bytes", [4, 1], ids=["f32", "int8"])
+@pytest.mark.parametrize("name", ["mnist", "celeba", "sr", "denoise"])
+def test_candidates_satisfy_mosaic_block_rules(name, dtype_bytes):
+    """Every tile the autotuner can pick passes the block-shape rules the
+    chip's compiler enforces and interpret mode does not: sublane-aligned
+    W windows, lane-multiple or whole-dim channel blocks, t_co <= 128."""
+    from repro import workloads
+    from repro.core.tiling import SUBLANE, halo_tile
+    from repro.kernels.deconv2d.kernel import check_mosaic_tiles
+
+    def up(x, m):
+        return -(-x // m) * m
+
+    for g in workloads.get(name).cfg.geometries():
+        cands = legal_tile_candidates(g, dtype_bytes, batch=64)
+        assert cands, g
+        for t_oh, t_ow, t_ci, t_co, t_n in cands:
+            ht_w = halo_tile(t_ow, g.kernel, g.stride, g.padding,
+                             align=SUBLANE)
+            check_mosaic_tiles(ht_w, up(g.out_w, t_ow) // t_ow, t_ci,
+                               up(g.c_in, t_ci), t_co, up(g.c_out, t_co),
+                               int8=dtype_bytes == 1)
+
+
+def test_refine_counts_refused_candidates_and_raises_when_all_fail(
+        tmp_cache, monkeypatch):
+    """A candidate the compiler refuses is counted, labelled with the
+    candidate, and skipped; when every candidate is refused the call
+    raises instead of falling back to the model's pick."""
+    import jax
+
+    from repro.obs import metrics as obsmetrics
+
+    refused = obsmetrics.default_registry().counter(
+        "autotune.refine_failures")
+    before = refused.total()
+    timed = []
+
+    def first_refused(geom, c, dtype, backend, batch=1):
+        timed.append(c)
+        if len(timed) == 1:
+            raise jax.errors.JaxRuntimeError(
+                "INTERNAL: Mosaic failed to compile TPU kernel")
+        return float(len(timed))
+
+    monkeypatch.setattr(autotune, "_time_candidate", first_refused)
+    c = choose_tiles(CELEBA_L2, jnp.float32, backend="pallas", refine=True,
+                     refine_top_k=3, batch=8, use_cache=False)
+    assert len(timed) == 3
+    assert c.source == "timed" and c == timed[1]   # fastest that compiled
+    assert refused.total() == before + 1
+
+    def all_refused(geom, c, dtype, backend, batch=1):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: vmem")
+
+    monkeypatch.setattr(autotune, "_time_candidate", all_refused)
+    with pytest.raises(RuntimeError, match="every refine candidate"):
+        choose_tiles(CELEBA_L2, jnp.float32, backend="pallas", refine=True,
+                     refine_top_k=3, batch=8, use_cache=False)

@@ -37,6 +37,26 @@ def test_unified_tile_is_common_and_optimal():
     assert t_per_layer <= t_unified * (1 + 1e-9)
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", TPU_V5E),            # off the chip: the v5e model
+    ("tpu", "TPU v5 lite", TPU_V5E),    # what a v5e reports
+    ("tpu", "TPU v5e", TPU_V5E),
+    ("tpu", "TPU v9", None),            # unknown TPU: refuse, never v5e
+])
+def test_planning_device_by_device_kind(monkeypatch, platform, kind, want):
+    from types import SimpleNamespace
+
+    from repro.core.dse import planning_device
+
+    monkeypatch.setattr(jax, "devices", lambda: [
+        SimpleNamespace(platform=platform, device_kind=kind)])
+    if want is None:
+        with pytest.raises(ValueError, match="no planning constants"):
+            planning_device()
+    else:
+        assert planning_device() is want
+
+
 def test_dse_on_pynq_reproduces_fig5_regime():
     """On the paper's PYNQ-Z2 point design, small tiles are bandwidth-bound
     (left of the slope) and attainable throughput is monotone until the roof."""

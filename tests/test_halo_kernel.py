@@ -6,11 +6,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.deconv import deconv2d_algorithm1_numpy
 from repro.core.tiling import (
-    DeconvGeometry, deconv_traffic, exact_input_extent, full_image_traffic,
-    halo_tile, kernel_vmem_bytes, out_size,
+    SUBLANE, DeconvGeometry, deconv_traffic, exact_input_extent,
+    full_image_traffic, halo_tile, kernel_vmem_bytes, out_size,
 )
 from repro.kernels.deconv2d import deconv2d, deconv2d_ref
 from repro.kernels.deconv2d.kernel import x_halo_blockspec
@@ -41,14 +42,44 @@ def test_x_blockspec_shape_and_index_map():
     k, s, p = 4, 2, 1
     t_oh, t_ow, t_ci = 8, 8, 32
     ht = halo_tile(t_oh, k, s, p)
-    bs = x_halo_blockspec(ht, ht, t_ci)
-    assert tuple(bs.block_shape) == (1, ht.extent, ht.extent, t_ci)
+    bs = x_halo_blockspec(ht, ht, t_ci, 1, n_tiles_w=8, n_ci=3)
+    assert tuple(bs.block_shape) == tuple(
+        pl.Element(d) for d in (1, ht.extent, ht.extent, t_ci))
     assert ht.extent == 6  # 8/2 + delta span 2: constant, image-independent
     # index map follows the output-tile grid, not a constant (0, 0) base
     for oh_t, ow_t, ci_t in [(0, 0, 0), (1, 0, 0), (2, 3, 1), (5, 7, 2)]:
         got = bs.index_map(1, oh_t, ow_t, 0, ci_t)
         assert got == (1, oh_t * ht.step + ht.base,
                        ow_t * ht.step + ht.base, ci_t * t_ci)
+    # a dim with a single window gets its constant offset, which Mosaic can
+    # prove tile-aligned without knowing the grid index is 0
+    one = x_halo_blockspec(ht, ht, t_ci, 1, n_tiles_w=1, n_ci=1)
+    assert one.index_map(1, 2, 0, 0, 0) == (1, 2 * ht.step + ht.base,
+                                            ht.base, 0)
+
+
+@pytest.mark.parametrize("k,s,p", [(4, 2, 1), (5, 2, 2), (4, 1, 0),
+                                   (7, 1, 0), (5, 3, 1)])
+def test_sublane_aligned_halo_tile(k, s, p):
+    """The W window Mosaic streams starts on a sublane boundary and spans
+    whole sublane tiles, yet still holds every row the tile's taps read:
+    the alignment moves rows in front of the window, never out of it."""
+    from repro.core.offsets import make_phase_plan
+
+    plan = make_phase_plan(k, s, p)
+    for t in (SUBLANE * s, 2 * SUBLANE * s, s, 3 * s):
+        exact = halo_tile(t, k, s, p)
+        ht = halo_tile(t, k, s, p, align=SUBLANE)
+        assert ht.base % SUBLANE == 0 and ht.extent % SUBLANE == 0
+        assert ht.base <= exact.base and ht.step == exact.step
+        assert ht.extent >= exact.extent
+        # tap rows of the tile: [local(delta_min), local(delta_max) + step)
+        assert ht.local_offset(plan.delta_min) >= 0
+        assert ht.local_offset(plan.delta_max) + ht.step <= ht.extent
+        # the same input rows as the unaligned window, window-relative
+        for d in (plan.delta_min, plan.delta_max):
+            assert (ht.base + ht.local_offset(d)
+                    == exact.base + exact.local_offset(d))
 
 
 def test_windows_cover_padded_input_exactly():
@@ -122,12 +153,13 @@ def test_batch_fused_kernel_matches_algorithm1(geom, rng):
 
 def test_x_blockspec_batch_tile():
     """The batch-tiled x BlockSpec streams t_n images' windows per program;
-    the (unblocked) index map advances by t_n elements on the batch dim."""
+    the element-offset index map advances by t_n elements on the batch dim."""
     k, s, p = 4, 2, 1
     t_oh, t_ci, t_n = 8, 32, 4
     ht = halo_tile(t_oh, k, s, p)
-    bs = x_halo_blockspec(ht, ht, t_ci, t_n)
-    assert tuple(bs.block_shape) == (t_n, ht.extent, ht.extent, t_ci)
+    bs = x_halo_blockspec(ht, ht, t_ci, t_n, n_tiles_w=8, n_ci=3)
+    assert tuple(bs.block_shape) == tuple(
+        pl.Element(d) for d in (t_n, ht.extent, ht.extent, t_ci))
     for nb, oh_t, ow_t, ci_t in [(0, 0, 0, 0), (3, 1, 2, 1), (7, 5, 0, 2)]:
         got = bs.index_map(nb, oh_t, ow_t, 0, ci_t)
         assert got == (nb * t_n, oh_t * ht.step + ht.base,

@@ -12,8 +12,9 @@ import numpy as np
 import pytest
 
 from repro.core.tiling import DeconvGeometry
-from repro.models.dcnn import (DcnnConfig, DeconvLayerCfg, generator_apply,
-                               generator_init, make_fused_generator)
+from repro.models.dcnn import (MNIST_DCNN, DcnnConfig, DeconvLayerCfg,
+                               generator_apply, generator_init,
+                               make_fused_generator)
 from repro.plan import (PLAN_SCHEMA_VERSION, DeconvPlan, NetworkPlan,
                         PlanSchemaError, build_layer_plan,
                         build_network_plan)
@@ -472,16 +473,18 @@ def test_tile_overrides_surface_does_not_warn(tmp_cache, rng):
 
 def test_plan_roofline_estimates(tmp_cache):
     """NetworkPlan owns the traffic/roofline numbers the benches report:
-    int8 plans model faster-than-fp32 network throughput at batch 64."""
-    p32 = build_network_plan(MNIST_SMALL, batch=64, backend="pallas")
-    params, _ = generator_init(jax.random.PRNGKey(0), MNIST_SMALL)
-    p8 = build_network_plan(MNIST_SMALL, batch=64, precision="int8",
+    int8 plans model faster-than-fp32 network throughput at batch 64, at
+    the paper's MNIST widths (the int8 kernel streams whole 128-lane
+    channel tiles, so a few-channel toy tower would price lane padding)."""
+    p32 = build_network_plan(MNIST_DCNN, batch=64, backend="pallas")
+    params, _ = generator_init(jax.random.PRNGKey(0), MNIST_DCNN)
+    p8 = build_network_plan(MNIST_DCNN, batch=64, precision="int8",
                             params=params, calib_batch=8)
     t32 = p32.traffic_report()
     t8 = p8.traffic_report()
-    assert set(t32) == set(t8) == set(range(len(MNIST_SMALL.layers)))
+    assert set(t32) == set(t8) == set(range(len(MNIST_DCNN.layers)))
     # int8 streams fewer bytes on every intermediate layer
-    for i in range(len(MNIST_SMALL.layers) - 1):
+    for i in range(len(MNIST_DCNN.layers) - 1):
         assert t8[i].total_bytes < t32[i].total_bytes
     a32 = p32.modeled_network_ops()
     a8 = p8.modeled_network_ops()

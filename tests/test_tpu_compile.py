@@ -1,0 +1,103 @@
+"""Compile-only checks of the deconv Pallas kernels for a TPU v5e.
+
+Each case lowers one kernel at the tiles the autotuner picks and compiles
+it with the TPU compiler for a described (not attached) ``v5e:2x2`` chip,
+so Mosaic's block-shape, alignment and VMEM rules are enforced here even
+though the tests run on the CPU, where the kernels otherwise execute in
+interpret mode.  Nothing runs; a pass says the chip's compiler accepts the
+kernel, nothing about its results or speed.  The topology is described
+inside a fixture: only the worker that runs this file loads the TPU
+library."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.autotune import choose_tiles
+from repro.kernels.deconv2d.int8 import _deconv2d_int8_jit
+from repro.kernels.deconv2d.ops import _deconv2d_jit
+from repro.kernels.deconv2d_sparse.ops import (_deconv2d_sparse_jit,
+                                               make_sparse_plan)
+from repro.models.dcnn import CELEBA_DCNN
+
+LAYERS = CELEBA_DCNN.geometries()
+LAST = len(LAYERS) - 1
+BUCKETS = (1, 64)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _shapes(sharding, *specs):
+    return tuple(jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                 for s, d in specs)
+
+
+def _assert_compiles(lowered):
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("batch", BUCKETS)
+@pytest.mark.parametrize("layer", range(len(LAYERS)))
+def test_dense_f32_compiles_for_v5e(one_chip, layer, batch):
+    g = LAYERS[layer]
+    t = choose_tiles(g, jnp.float32, "pallas", batch=batch, use_cache=False)
+    args = _shapes(one_chip,
+                   ((batch, g.in_h, g.in_w, g.c_in), jnp.float32),
+                   ((g.kernel, g.kernel, g.c_in, g.c_out), jnp.float32),
+                   ((g.c_out,), jnp.float32))
+    _assert_compiles(_deconv2d_jit.lower(
+        *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
+        CELEBA_DCNN.layers[layer].activation, False))
+
+
+@pytest.mark.parametrize("batch", BUCKETS)
+@pytest.mark.parametrize("layer", (1, LAST))
+def test_int8_compiles_for_v5e(one_chip, layer, batch):
+    g = LAYERS[layer]
+    last = layer == LAST   # the last layer's epilogue emits f32 images
+    t = choose_tiles(g, jnp.int8, "pallas", batch=batch, use_cache=False,
+                     out_dtype_bytes=4 if last else None)
+    args = _shapes(one_chip,
+                   ((batch, g.in_h, g.in_w, g.c_in), jnp.int8),
+                   ((g.kernel, g.kernel, g.c_in, g.c_out), jnp.int8),
+                   ((g.c_out,), jnp.float32), ((g.c_out,), jnp.float32))
+    _assert_compiles(_deconv2d_int8_jit.lower(
+        *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
+        CELEBA_DCNN.layers[layer].activation, None if last else 0.05,
+        False))
+
+
+@pytest.mark.parametrize("batch", BUCKETS)
+@pytest.mark.parametrize("layer", (1, LAST))
+def test_sparse_compiles_for_v5e(one_chip, layer, batch):
+    g = LAYERS[layer]
+    t = choose_tiles(g, jnp.float32, "pallas_sparse", batch=batch,
+                     use_cache=False)
+    w = np.random.RandomState(0).randn(
+        g.kernel, g.kernel, g.c_in, g.c_out).astype(np.float32)
+    ci_idx, valid, taps = make_sparse_plan(w, g.stride, g.padding, t.t_ci,
+                                           t.t_co)
+    args = _shapes(one_chip,
+                   ((batch, g.in_h, g.in_w, g.c_in), jnp.float32),
+                   (w.shape, jnp.float32), ((g.c_out,), jnp.float32),
+                   (ci_idx.shape, jnp.int32), (valid.shape, jnp.int32),
+                   (taps.shape, jnp.int32))
+    _assert_compiles(_deconv2d_sparse_jit.lower(
+        *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
+        CELEBA_DCNN.layers[layer].activation, False))
