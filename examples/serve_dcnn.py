@@ -25,7 +25,12 @@ that is the only way to hold the SLO.
 and writes a Chrome/Perfetto ``trace_event`` JSON on exit — open it at
 https://ui.perfetto.dev to see admission, EDF queue wait, wave dispatch,
 per-bucket kernel calls and collect as one timeline, with retries and
-remesh events as instant markers.
+remesh events as instant markers.  Each engine bucket call shows its
+upload, call, wait and copy back; each span names the span it ran under
+(``parent``), and a request's ``queue_wait`` the ``wave`` that served it.
+Run under ``jax.profiler`` the same spans also land in the profile, next
+to the device's ops.  The line it prints counts the events exported and
+those the tracer's ring dropped (the oldest, once it is full).
 """
 import argparse
 import os
@@ -121,8 +126,10 @@ def main():
     finally:
         if args.trace:
             obstrace.disable()
-            n = obstrace.get_tracer().export(args.trace)
-            print(f"trace: {n} events -> {args.trace} "
+            tracer = obstrace.get_tracer()
+            n = tracer.export(args.trace)
+            print(f"trace: {n} events -> {args.trace}, {tracer.dropped} "
+                  f"dropped by the {tracer.capacity}-event ring "
                   f"(open at https://ui.perfetto.dev)")
 
 

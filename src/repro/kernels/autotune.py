@@ -396,14 +396,7 @@ def _time_candidate(
     from .deconv2d.ops import suppress_tile_warnings
 
     from ..obs import clock as obsclock
-    from ..obs import metrics as obsmetrics
 
-    # refine timings are observability, not just a ranking input: the
-    # process registry keeps them as a histogram so a tuning run's
-    # run-to-run spread is inspectable next to the serve-path Table II
-    hist = obsmetrics.default_registry().histogram(
-        "autotune.refine_seconds",
-        "per-rep candidate wall clock during refine=True tuning")
     kwargs = choice.as_kwargs()
     with suppress_tile_warnings():  # internal harness, not a user call
         jax.block_until_ready(
@@ -414,8 +407,6 @@ def _time_candidate(
             jax.block_until_ready(
                 fn(x, w, None, geom.stride, geom.padding, **kwargs))
             ts.append(obsclock.now() - t0)
-            hist.observe(ts[-1], backend=backend, batch=batch,
-                         dtype=np.dtype(dtype).name)
     return float(np.median(ts))
 
 

@@ -37,7 +37,7 @@ from ...core.offsets import PhasePlan, make_phase_plan
 from ...core.tiling import SUBLANE, HaloTile, halo_tile
 from ...quant.qmath import QMAX, quantize_symmetric
 from .kernel import (COMPILER_PARAMS, apply_activation, check_mosaic_tiles,
-                     x_halo_blockspec)
+                     kernel_name, x_halo_blockspec)
 
 
 def requant_epilogue(acc_i32: jax.Array, scale: jax.Array, bias: jax.Array,
@@ -125,6 +125,7 @@ def deconv2d_int8_pallas_call(
     activation: Optional[str] = None,
     out_scale: Optional[float] = None,
     interpret: bool = False,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     n, ihp, iwp, cip = x_padded.shape
     k = w.shape[0]
@@ -179,7 +180,7 @@ def deconv2d_int8_pallas_call(
         ],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        name="deconv2d_int8_halo_reverse_loop",
+        name=kernel_name("int8_halo_reverse_loop", layer),
     )(x_padded, w, scale, b)
 
 
@@ -187,7 +188,7 @@ def deconv2d_int8_pallas_call(
     jax.jit,
     static_argnames=(
         "stride", "padding", "t_oh", "t_ow", "t_ci", "t_co", "t_n",
-        "activation", "out_scale", "interpret",
+        "activation", "out_scale", "interpret", "layer",
     ),
 )
 def _deconv2d_int8_jit(
@@ -205,6 +206,7 @@ def _deconv2d_int8_jit(
     activation: Optional[str],
     out_scale: Optional[float],
     interpret: bool,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     n, ih, iw, ci = x.shape
     k, _, _, co = w.shape
@@ -233,6 +235,7 @@ def _deconv2d_int8_jit(
         activation=activation,
         out_scale=out_scale,
         interpret=interpret,
+        layer=layer,
     )
     return y[:n, :oh, :ow, :co]
 
@@ -254,6 +257,7 @@ def deconv2d_int8(
     interpret: Optional[bool] = None,
     autotune: bool = True,
     plan=None,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     """Quantized transposed conv through the int8 reverse-loop kernel.
 
@@ -268,7 +272,8 @@ def deconv2d_int8(
     tile resolution entirely.  Without a plan, unspecified tile factors
     resolve through the dtype-aware autotuner — the int8 byte width flows
     into the VMEM/traffic models and the int8 MXU peak into the roofline
-    ranking — and explicit tile kwargs are deprecated.
+    ranking — and explicit tile kwargs are deprecated.  ``layer`` names
+    the kernel, as in `ops.deconv2d`.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -284,7 +289,7 @@ def deconv2d_int8(
         return _deconv2d_int8_jit(
             x, w, jnp.asarray(scale), b, plan.geometry.stride,
             plan.geometry.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
-            activation, out_scale, interpret,
+            activation, out_scale, interpret, layer,
         )
     if stride is None or padding is None:
         raise TypeError(
@@ -300,5 +305,5 @@ def deconv2d_int8(
     )
     return _deconv2d_int8_jit(
         x, w, jnp.asarray(scale), b, stride, padding, t_oh, t_ow, t_ci,
-        t_co, t_n, activation, out_scale, interpret,
+        t_co, t_n, activation, out_scale, interpret, layer,
     )

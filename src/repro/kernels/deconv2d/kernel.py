@@ -195,6 +195,13 @@ def _deconv2d_kernel(
         o_ref[...] = apply_activation(y, activation).astype(out_dtype)
 
 
+def kernel_name(kind: str, layer: Optional[int]) -> str:
+    """A deconvolution kernel's Pallas call name, ``deconv2d_<kind>``, or
+    ``deconv2d_l<layer>_<kind>`` for layer ``layer`` of a tower: each
+    layer's kernel is then its own op in a profile."""
+    return "deconv2d_" + ("" if layer is None else f"l{layer}_") + kind
+
+
 def deconv2d_pallas_call(
     x_padded: jax.Array,     # (N, IHp, IWp, CIp)  host-padded
     w: jax.Array,            # (K, K, CIp, COp)
@@ -210,6 +217,7 @@ def deconv2d_pallas_call(
     t_n: int = 1,
     activation: Optional[str] = None,
     interpret: bool = False,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     n, ihp, iwp, cip = x_padded.shape
     k = w.shape[0]
@@ -261,5 +269,5 @@ def deconv2d_pallas_call(
         ],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        name="deconv2d_halo_reverse_loop",
+        name=kernel_name("halo_reverse_loop", layer),
     )(x_padded, w, b)

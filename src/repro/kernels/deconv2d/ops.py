@@ -116,7 +116,7 @@ def halo_pad_geometry(n: int, ih: int, iw: int, ci: int, co: int,
     jax.jit,
     static_argnames=(
         "stride", "padding", "t_oh", "t_ow", "t_ci", "t_co", "t_n",
-        "activation", "interpret",
+        "activation", "interpret", "layer",
     ),
 )
 def _deconv2d_jit(
@@ -132,6 +132,7 @@ def _deconv2d_jit(
     t_n: int,
     activation: Optional[str],
     interpret: bool,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     n, ih, iw, ci = x.shape
     k, _, _, co = w.shape
@@ -157,6 +158,7 @@ def _deconv2d_jit(
         t_oh=t_oh, t_ow=t_ow, t_ci=t_ci, t_co=t_co, t_n=t_n,
         activation=activation,
         interpret=interpret,
+        layer=layer,
     )
     return y[:n, :oh, :ow, :co]
 
@@ -216,6 +218,7 @@ def deconv2d(
     interpret: Optional[bool] = None,
     autotune: bool = True,
     plan=None,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     """Transposed conv y = act(deconv(x, w) + b) via the reverse-loop kernel.
 
@@ -236,6 +239,9 @@ def deconv2d(
     batch tile: each grid program owns ``t_n`` images and the tap matmuls
     contract over ``t_n * T_OH/S * T_OW/S`` rows (the batch is zero-padded
     to a ``t_n`` multiple and sliced back).
+
+    ``layer``, the layer's index in its tower, names the kernel
+    (`kernel.kernel_name`); it changes no plan and no result.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -247,6 +253,7 @@ def deconv2d(
         return _deconv2d_jit(
             x, w, b, plan.geometry.stride, plan.geometry.padding,
             t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n, activation, interpret,
+            layer,
         )
     if stride is None or padding is None:
         raise TypeError("deconv2d needs stride and padding (or a plan=)")
@@ -258,5 +265,5 @@ def deconv2d(
     )
     return _deconv2d_jit(
         x, w, b, stride, padding, t_oh, t_ow, t_ci, t_co, t_n, activation,
-        interpret,
+        interpret, layer,
     )

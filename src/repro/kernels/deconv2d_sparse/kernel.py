@@ -29,7 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.offsets import PhasePlan
 from ...core.tiling import SUBLANE, HaloTile, halo_tile
 from ..deconv2d.kernel import (COMPILER_PARAMS, apply_activation,
-                               check_mosaic_tiles, window_start)
+                               check_mosaic_tiles, kernel_name,
+                               window_start)
 
 
 def build_schedule(block_tap_mask: np.ndarray):
@@ -142,6 +143,7 @@ def deconv2d_sparse_pallas_call(
     t_n: int = 1,
     activation=None,
     interpret: bool = False,
+    layer=None,
 ) -> jax.Array:
     n, ihp, iwp, cip = x_padded.shape
     k = w.shape[0]
@@ -213,5 +215,5 @@ def deconv2d_sparse_pallas_call(
         out_shape=jax.ShapeDtypeStruct((n, ohp, owp, cop), x_padded.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        name="deconv2d_sparse_reverse_loop",
+        name=kernel_name("sparse_reverse_loop", layer),
     )(ci_idx, valid, tap_mask, x_padded, w, b)

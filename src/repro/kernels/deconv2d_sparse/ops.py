@@ -38,11 +38,12 @@ def make_sparse_plan(
 @functools.partial(
     jax.jit,
     static_argnames=("stride", "padding", "t_oh", "t_ow", "t_ci", "t_co",
-                     "t_n", "activation", "interpret"),
+                     "t_n", "activation", "interpret", "layer"),
 )
 def _deconv2d_sparse_jit(
     x, w, b, ci_idx, valid, tap_mask,
     stride, padding, t_oh, t_ow, t_ci, t_co, t_n, activation, interpret,
+    layer=None,
 ):
     n, ih, iw, ci = x.shape
     k, _, _, co = w.shape
@@ -59,7 +60,7 @@ def _deconv2d_sparse_jit(
         xp, wp, bp, ci_idx, valid, tap_mask,
         plan=plan, ohp=ohp, owp=owp,
         t_oh=t_oh, t_ow=t_ow, t_ci=t_ci, t_co=t_co, t_n=t_n,
-        activation=activation, interpret=interpret,
+        activation=activation, interpret=interpret, layer=layer,
     )
     return y[:n, :oh, :ow, :co]
 
@@ -79,6 +80,7 @@ def deconv2d_sparse(
     interpret: Optional[bool] = None,
     autotune: bool = True,
     plan=None,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     """Sparse transposed conv; weights are expected pre-pruned (zeros).
 
@@ -88,7 +90,8 @@ def deconv2d_sparse(
     same t_ci/t_co; both avoid re-deriving the static schedule, an
     O(weights) host computation, on every call.  ``t_n`` batch-tiles the
     grid exactly as in the dense kernel (the schedule is batch-
-    independent, so one plan serves every bucket)."""
+    independent, so one plan serves every bucket).  ``layer`` names the
+    kernel, as in `deconv2d.ops.deconv2d`."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if plan is not None and hasattr(plan, "geometry"):
@@ -126,5 +129,5 @@ def deconv2d_sparse(
     return _deconv2d_sparse_jit(
         x, w, b, jnp.asarray(ci_idx), jnp.asarray(valid),
         jnp.asarray(tap_mask), stride, padding,
-        t_oh, t_ow, t_ci, t_co, t_n, activation, interpret,
+        t_oh, t_ow, t_ci, t_co, t_n, activation, interpret, layer,
     )

@@ -182,9 +182,11 @@ def generator_apply(
     layer index -> precomputed `make_sparse_plan` result for
     backend="pallas_sparse" (see serve.DcnnServeEngine).
 
-    On the pallas backends each layer's bias + activation run fused in the
-    kernel's flush phase, so the chain never materializes a pre-activation
-    layer in HBM; the other backends apply the activation separately.
+    On the pallas backends each layer's kernel is named with its index
+    (``deconv2d_l<i>_...``, see `kernels.deconv2d.kernel.kernel_name`),
+    and its bias + activation run fused in the kernel's flush phase, so
+    the chain never materializes a pre-activation layer in HBM; the other
+    backends apply the activation separately.
     ``return_intermediates=True`` additionally returns the list of
     per-layer *inputs* (the tensors quantization calibrates against —
     see quant.calibrate): ``(images, [x_0, ..., x_{L-1}])``.
@@ -213,25 +215,25 @@ def generator_apply(
             from ..kernels.deconv2d import deconv2d
             from ..kernels.deconv2d.ops import suppress_tile_warnings
             if lp is not None:
-                x = deconv2d(x, w, b, plan=lp)
+                x = deconv2d(x, w, b, plan=lp, layer=i)
             else:
                 # supported legacy override surface: the expansion into
                 # tile kwargs is ours, not the user's — don't warn
                 with suppress_tile_warnings():
                     x = deconv2d(
                         x, w, b, l.stride, l.padding,
-                        activation=l.activation,
+                        activation=l.activation, layer=i,
                         **_tile_kwargs((tile_overrides or {}).get(i)))
         elif backend == "pallas_sparse":
             from ..kernels.deconv2d.ops import suppress_tile_warnings
             from ..kernels.deconv2d_sparse import deconv2d_sparse
             if lp is not None:
-                x = deconv2d_sparse(x, w, b, plan=lp)
+                x = deconv2d_sparse(x, w, b, plan=lp, layer=i)
             else:
                 with suppress_tile_warnings():
                     x = deconv2d_sparse(
                         x, w, b, l.stride, l.padding,
-                        activation=l.activation,
+                        activation=l.activation, layer=i,
                         plan=(sparse_plans or {}).get(i),
                         **_tile_kwargs((tile_overrides or {}).get(i)))
         else:

@@ -7,7 +7,9 @@ counters land in one place.  Series are labelable by any string keys —
 the serve stack uses ``(net, precision, bucket, tenant)`` — and a
 histogram keeps streaming moments (count, sum, sum of squares) plus
 fixed bucket counts, so the paper's Table II statistics (mean, std,
-run-to-run CV) reduce in O(1) without retaining samples.
+run-to-run CV) reduce in O(1) without retaining samples, and a
+histogram over `log_buckets` gives percentiles to a stated relative
+error (`Histogram.quantile`).
 
 Locking discipline (checked by ``repro.analysis.check`` lint): each
 metric owns one ``threading.Lock`` guarding its series dict; the
@@ -16,6 +18,7 @@ are leaves — no metric method calls back into the registry.
 """
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -27,6 +30,7 @@ __all__ = [
     "MetricsRegistry",
     "MetricTypeError",
     "default_registry",
+    "log_buckets",
 ]
 
 LabelKey = Tuple[Tuple[str, str], ...]
@@ -34,6 +38,15 @@ LabelKey = Tuple[Tuple[str, str], ...]
 
 class MetricTypeError(TypeError):
     """A metric name was re-requested with a different type."""
+
+
+def log_buckets(lo: float, hi: float, per_decade: int) -> Tuple[float, ...]:
+    """Histogram bounds ``lo, lo*r, lo*r**2, ...`` up to ``hi``, with
+    ``r = 10 ** (1 / per_decade)``.  Between ``lo`` and ``hi``,
+    `Histogram.quantile` over them lies within ``sqrt(r) - 1`` of a sample
+    of the right rank: 2.92% at 40 bounds a decade."""
+    n = round(per_decade * math.log10(hi / lo))
+    return tuple(lo * 10 ** (k / per_decade) for k in range(n + 1))
 
 
 def _label_key(labels: Dict[str, object]) -> LabelKey:
@@ -144,11 +157,8 @@ class _HistSeries:
             self.min = value
         if value > self.max:
             self.max = value
-        for i, b in enumerate(bounds):
-            if value <= b:
-                self.bucket_counts[i] += 1
-                return
-        self.bucket_counts[-1] += 1
+        # first bound >= value; past the last bound, the overflow slot
+        self.bucket_counts[bisect.bisect_left(bounds, value)] += 1
 
     def merge_into(self, other: "_HistSeries") -> None:
         other.count += self.count
@@ -215,6 +225,32 @@ class Histogram:
                 if _matches(key, match):
                     s.merge_into(pooled)
         return pooled.stats()
+
+    def quantile(self, q: float, **match: object) -> Optional[float]:
+        """The nearest-rank ``q``-quantile (0 < q <= 1), pooled across
+        every series matching a label subset; None without samples.
+
+        The sample of that rank lies in one bucket, between the bounds
+        around it (the observed min and max at the ends); the estimate is
+        their geometric mean, so its relative error is at most the square
+        root of the bounds' ratio, less one (see `log_buckets`)."""
+        pooled = _HistSeries(len(self.bounds))
+        with self._lock:
+            for key, s in self._series.items():
+                if _matches(key, match):
+                    s.merge_into(pooled)
+        if pooled.count == 0:
+            return None
+        rank = max(1, math.ceil(q * pooled.count))
+        seen = 0
+        for i, c in enumerate(pooled.bucket_counts):
+            seen += c
+            if seen >= rank:
+                break
+        lo = max(self.bounds[i - 1] if i > 0 else pooled.min, pooled.min)
+        hi = min(self.bounds[i] if i < len(self.bounds) else pooled.max,
+                 pooled.max)
+        return math.sqrt(lo * hi) if lo > 0 else 0.5 * (lo + hi)
 
     def label_values(self, label: str) -> List[str]:
         """Distinct observed values of one label key, sorted."""

@@ -15,7 +15,17 @@ Design constraints, in order:
 * **monotonic-clock only** — all timestamps come from
   :func:`repro.obs.clock.now`; wall-clock never leaks into a trace.
 * **ring-buffered** — a bounded ``deque`` keeps the newest ``capacity``
-  events; a long soak can stay traced without growing memory.
+  events; a long soak can stay traced without growing memory.  The
+  events it evicts are counted (:attr:`Tracer.dropped`), so a reader can
+  tell a complete window from a truncated one.
+* **linked** — every recorded span carries an ``id`` arg, and a span
+  recorded on a thread while a scoped span is open there names it as
+  ``parent``: a request's ``rid`` leads to its wave, the wave to its
+  bucket calls, and each call to its phases.
+* **on the profiler's clock** — while enabled, each scoped span is also
+  entered as a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  profile taken meanwhile holds the program's spans next to the device
+  ops.  JAX is imported on first use only: ``obs`` imports without it.
 
 Three recording styles cover the serve stack's shapes:
 
@@ -23,11 +33,12 @@ Three recording styles cover the serve stack's shapes:
 * ``h = tracer.begin("queue_wait"); ... tracer.end(h)`` — spans that
   start on one thread (submit) and finish on another (worker).
 * ``tracer.complete(name, t0, t1)`` — retroactive, for code that already
-  timed itself (dispatch retries keep their own ``t0``).
+  timed itself (submit, collect, plan builds).
 """
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import threading
@@ -35,7 +46,7 @@ from typing import Dict, List, Optional, Tuple
 
 from . import clock
 
-__all__ = ["Tracer", "get_tracer", "enable", "disable"]
+__all__ = ["Tracer", "get_tracer", "enable", "disable", "NULL_SPAN"]
 
 
 class _NullSpan:
@@ -49,14 +60,33 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args: object) -> None:
+        pass
+
 
 _NULL = _NullSpan()
+# for call sites that pick a span or nothing without building its args:
+# ``with (tracer.span(...) if tracer.enabled else NULL_SPAN):``
+NULL_SPAN = _NULL
+
+_annotation_cls = None
+
+
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``; JAX is imported on
+    the first call, not with this module."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
 
 
 class _Span:
-    """Context-manager span; records one complete event on exit."""
+    """Context-manager span; records one complete event on exit, and is a
+    profiler annotation of the same name while open."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict) -> None:
@@ -65,16 +95,34 @@ class _Span:
         self._cat = cat
         self._args = args
         self._t0 = 0.0
+        self._annotation = None
 
+    def set(self, **args: object) -> None:
+        """Add args known only once the span's work has run."""
+        self._args.update(args)
+
+    # the span's interval holds its own bookkeeping (clock read first on
+    # entry, and on exit last, inside the ring's lock), so spans run back
+    # to back leave almost no gap
     def __enter__(self) -> "_Span":
         self._t0 = clock.now()
+        tracer = self._tracer
+        tracer._open_spans().append(tracer._linked(self._args)["id"])
+        self._annotation = _profiler_annotation(self._name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        tracer = self._tracer
+        tracer._open_spans().pop()
         if exc_type is not None:
             self._args["error"] = exc_type.__name__
-        self._tracer.complete(self._name, self._t0, clock.now(),
-                              cat=self._cat, **self._args)
+        if tracer._enabled:
+            th = threading.current_thread()
+            tracer._append(tracer._event("X", self._name, self._cat,
+                                         self._t0, self._args),
+                           th.ident or 0, th.name, open_t0=self._t0)
         return False
 
 
@@ -105,6 +153,13 @@ class Tracer:
         # OS thread ident -> (small display tid, thread name at first record)
         self._tids: Dict[int, Tuple[int, str]] = {}
         self._enabled = bool(enabled)
+        # events the full ring evicted since the last clear()
+        self.dropped = 0
+        # read once (and again on enable): a syscall per event is costly
+        self._pid = os.getpid()
+        self._ids = itertools.count(1)
+        # per thread: ids of the scoped spans open there, innermost last
+        self._local = threading.local()
 
     # -- enable/disable: plain flag writes, deliberately lock-free so the
     # -- disabled fast path is a single unguarded attribute read
@@ -113,25 +168,42 @@ class Tracer:
         return self._enabled
 
     def enable(self) -> None:
+        self._pid = os.getpid()
         self._enabled = True
 
     def disable(self) -> None:
         self._enabled = False
 
     # -- recording ----------------------------------------------------------
+    def _open_spans(self) -> List[int]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def _linked(self, args: dict) -> dict:
+        """``args`` with a fresh span id and the open scoped span as
+        parent."""
+        args["id"] = next(self._ids)
+        stack = self._open_spans()
+        if stack:
+            args["parent"] = stack[-1]
+        return args
+
     def span(self, name: str, cat: str = "serve", **args: object):
         """Scoped span; returns a shared null object while disabled."""
         if not self._enabled:
             return _NULL
-        return _Span(self, name, cat, dict(args))
+        return _Span(self, name, cat, args)
 
     def begin(self, name: str, cat: str = "serve", **args: object):
         """Start a span that may be ended from another thread."""
         if not self._enabled:
             return _NULL
         th = threading.current_thread()
-        return SpanHandle(name, cat, dict(args), clock.now(),
-                          th.ident or 0, th.name)
+        return SpanHandle(name, cat, {**args, "id": next(self._ids)},
+                          clock.now(), th.ident or 0, th.name)
 
     def end(self, handle, **extra: object) -> None:
         """Finish a :meth:`begin` handle; attributed to the begin thread."""
@@ -150,7 +222,7 @@ class Tracer:
             return
         th = threading.current_thread()
         self._record("X", name, cat, t0, t1, th.ident or 0, th.name,
-                     dict(args))
+                     self._linked(dict(args)))
 
     def instant(self, name: str, cat: str = "serve", **args: object) -> None:
         """Thread-scoped instant marker (retries, remesh, sheds...)."""
@@ -163,14 +235,28 @@ class Tracer:
 
     def _record(self, ph: str, name: str, cat: str, t0: float, t1: float,
                 ident: int, tname: str, args: dict) -> None:
-        ev = {"ph": ph, "name": name, "cat": cat, "ts": t0 * 1e6,
-              "pid": os.getpid(), "args": args}
+        ev = self._event(ph, name, cat, t0, args)
         if ph == "X":
             ev["dur"] = max(t1 - t0, 0.0) * 1e6
         else:
             ev["s"] = "t"
+        self._append(ev, ident, tname)
+
+    def _event(self, ph: str, name: str, cat: str, t0: float,
+               args: dict) -> dict:
+        return {"ph": ph, "name": name, "cat": cat, "ts": t0 * 1e6,
+                "pid": self._pid, "args": args}
+
+    def _append(self, ev: dict, ident: int, tname: str,
+                open_t0: Optional[float] = None) -> None:
+        """Add ``ev`` to the ring; given ``open_t0``, ``ev`` is a span that
+        started then and ends now, its own recording included."""
         with self._lock:
             ev["tid"] = self._tid_locked(ident, tname)
+            if open_t0 is not None:
+                ev["dur"] = max(clock.now() - open_t0, 0.0) * 1e6
+            if len(self._events) == self.capacity:
+                self.dropped += 1
             self._events.append(ev)
 
     def _tid_locked(self, ident: int, tname: str) -> int:
@@ -194,13 +280,14 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._tids.clear()
+            self.dropped = 0
 
     def to_chrome(self) -> dict:
         """Chrome/Perfetto ``trace_event`` document (JSON object format)."""
         with self._lock:
             events = list(self._events)
             tids = dict(self._tids)
-        pid = os.getpid()
+        pid = self._pid
         meta: List[dict] = [{
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
             "args": {"name": "repro-serve"}}]

@@ -67,7 +67,8 @@ def quantized_generator_apply(
         lq = qp[f"l{i}"]
         if plan is not None:
             x = deconv2d_int8(x, lq["w_q"], lq["scale"], lq["b"],
-                              plan=plan.layers[i], interpret=interpret)
+                              plan=plan.layers[i], interpret=interpret,
+                              layer=i)
         else:
             from ..kernels.deconv2d.ops import suppress_tile_warnings
 
@@ -78,7 +79,7 @@ def quantized_generator_apply(
                     x, lq["w_q"], lq["scale"], lq["b"], l.stride,
                     l.padding, activation=l.activation,
                     out_scale=qcfg.out_scale(i), interpret=interpret,
-                    **_tile_kwargs((tile_overrides or {}).get(i)))
+                    layer=i, **_tile_kwargs((tile_overrides or {}).get(i)))
         x = constrain(x, "batch", None, None, None)
     return x
 

@@ -17,6 +17,7 @@ the largest fitting bucket."""
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 import time
 import warnings
@@ -589,6 +590,10 @@ class DcnnServeEngine:
                 self.trace_counts[_b] = self.trace_counts.get(_b, 0) + 1
                 return _apply(p, z)
 
+            # the step's stable name in profiles and HLO (jit_<name>)
+            fn.__name__ = fn.__qualname__ = re.sub(
+                r"\W", "_", f"serve_{self.cfg.name}_{self.precision}"
+                f"_b{bucket}")
             self._fns[bucket] = jax.jit(
                 fn, **shardings,
                 **(dict(donate_argnums=(1,)) if self._donate else {}))
@@ -629,7 +634,11 @@ class DcnnServeEngine:
         run-to-run CV samples (Table II accounting).  `TransientCallError`
         is retried up to ``max_retries`` times then raised as
         `EngineDegraded`; `DeviceLossError` escapes to `generate`, which
-        remeshes."""
+        remeshes.
+
+        With the tracer on, each attempt is a ``dispatch b<bucket>`` span
+        split into its phases (`_traced_call`); off, the call is one
+        expression, with no wait between the call and the copy back."""
         fn = self._get_fn(bucket)
         attempts = self.config.max_retries + 1
         for attempt in range(attempts):
@@ -637,14 +646,18 @@ class DcnnServeEngine:
                 self._heartbeat.arm()
             try:
                 traces_before = self.trace_counts.get(bucket, 0)
-                # the injector hook sits inside the timed window: an
-                # injected SlowCall is a slow *dispatch*, visible to the
-                # straggler monitor exactly like a real one
-                t0 = obsclock.now()
-                if self.fault_injector is not None:
-                    self.fault_injector.before_call(bucket)
-                y = np.asarray(fn(self.params, jnp.asarray(chunk)))
-                dt = obsclock.now() - t0
+                if self._tracer.enabled:
+                    y, dt = self._traced_call(fn, bucket, chunk, attempt,
+                                              traces_before)
+                else:
+                    # the injector hook sits inside the timed window: an
+                    # injected SlowCall is a slow *dispatch*, visible to
+                    # the straggler monitor exactly like a real one
+                    t0 = obsclock.now()
+                    if self.fault_injector is not None:
+                        self.fault_injector.before_call(bucket)
+                    y = np.asarray(fn(self.params, jnp.asarray(chunk)))
+                    dt = obsclock.now() - t0
             except TransientCallError as e:
                 with self._qlock:
                     self.fault_stats["transient_failures"] += 1
@@ -683,10 +696,35 @@ class DcnnServeEngine:
                     self._tracer.instant("straggler", cat="fault",
                                          bucket=bucket, seconds=dt,
                                          **self._mlabels)
-            self._tracer.complete(f"dispatch b{bucket}", t0, t0 + dt,
-                                  cat="engine", bucket=bucket, steady=steady,
-                                  retried=retried, **self._mlabels)
             return y, dt, steady, retried
+
+    def _traced_call(self, fn, bucket: int, chunk: np.ndarray,
+                     attempt: int, traces_before: int):
+        """One attempt of a bucket call as a ``dispatch b<bucket>`` span
+        with four phases inside it: ``dispatch.upload`` (host side of the
+        host-to-device copy), ``dispatch.call`` (until the jitted call
+        returns: the enqueue), ``dispatch.wait`` (until the device is
+        done) and ``dispatch.copy_back`` (device-to-host copy of the
+        ready result).  Returns ``(images, seconds)`` as the untraced
+        path does; the wait costs one more host wake-up."""
+        tr = self._tracer
+        with tr.span(f"dispatch b{bucket}", cat="engine", bucket=bucket,
+                     **self._mlabels) as span:
+            t0 = obsclock.now()
+            if self.fault_injector is not None:
+                self.fault_injector.before_call(bucket)
+            with tr.span("dispatch.upload", cat="engine"):
+                x = jnp.asarray(chunk)
+            with tr.span("dispatch.call", cat="engine"):
+                y = fn(self.params, x)
+            with tr.span("dispatch.wait", cat="engine"):
+                y = jax.block_until_ready(y)
+            with tr.span("dispatch.copy_back", cat="engine"):
+                y = np.asarray(y)
+            dt = obsclock.now() - t0
+            span.set(steady=self.trace_counts.get(bucket, 0) == traces_before,
+                     retried=attempt > 0)
+        return y, dt
 
     def _remesh(self, keep: int) -> None:
         """Elastic recovery from device loss: shrink onto the surviving
@@ -830,63 +868,63 @@ class DcnnServeEngine:
         raising."""
         z = np.asarray(z, dtype=self.cfg.dtype)
         n = z.shape[0]
-        t_gen = obsclock.now()
-        outs: List[np.ndarray] = []
-        i = 0
-        chunks = self.plan_chunks(n)
-        while chunks:
-            take, bucket = chunks[0]
-            chunk = z[i:i + take]
-            pad = bucket - take
-            if pad:
-                chunk = np.concatenate(
-                    [chunk, np.zeros((pad,) + z.shape[1:], z.dtype)],
-                    axis=0)
-            try:
-                y, dt, steady, retried = self._dispatch(bucket, chunk)
-            except DeviceLossError as e:
-                self._remesh(e.keep)
-                chunks = self.plan_chunks(n - i)
-                continue
-            chunks.pop(0)
-            if pad:
-                self.stats["padded_images"] += pad
-                self._m_padded.inc(pad, **self._mlabels)
-            if steady:
-                # steady-state call: a call that traced (compiled) would
-                # poison the learned rates by orders of magnitude
-                bs = self.bucket_stats.setdefault(
-                    bucket, {"calls": 0, "images": 0, "seconds": 0.0,
-                             "sumsq_seconds": 0.0, "tainted_calls": 0,
-                             "tainted_seconds": 0.0})
-                if retried:
-                    # outcome-tagged: a dispatch that needed transient
-                    # retries is real work but not a healthy run — its
-                    # wall clock stays out of the Table II mean/std/CV
-                    # samples (which are *run-to-run variation of the
-                    # healthy path*, the paper's predictability claim)
-                    bs["tainted_calls"] += 1
-                    bs["tainted_seconds"] += dt
-                    self._m_tainted.inc(bucket=bucket, **self._mlabels)
-                else:
-                    bs["calls"] += 1
-                    bs["images"] += take
-                    # running first/second moments of the per-call wall
-                    # clock (the paper's Table II mean/std methodology)
-                    # — O(1) state, not a per-call sample list a
-                    # long-lived engine would grow without bound
-                    bs["seconds"] += dt
-                    bs["sumsq_seconds"] += dt * dt
-                    self._m_dispatch.observe(dt, bucket=bucket,
-                                             **self._mlabels)
-            outs.append(y[:take])
-            i += take
-        self.stats["generate_calls"] += 1
-        self.stats["images"] += n
-        self._m_generate_calls.inc(**self._mlabels)
-        self._m_images.inc(n, **self._mlabels)
-        self._tracer.complete("generate", t_gen, obsclock.now(),
-                              cat="engine", rows=n, **self._mlabels)
+        tr = self._tracer
+        with (tr.span("generate", cat="engine", rows=n, **self._mlabels)
+              if tr.enabled else obstrace.NULL_SPAN):
+            outs: List[np.ndarray] = []
+            i = 0
+            chunks = self.plan_chunks(n)
+            while chunks:
+                take, bucket = chunks[0]
+                chunk = z[i:i + take]
+                pad = bucket - take
+                if pad:
+                    chunk = np.concatenate(
+                        [chunk, np.zeros((pad,) + z.shape[1:], z.dtype)],
+                        axis=0)
+                try:
+                    y, dt, steady, retried = self._dispatch(bucket, chunk)
+                except DeviceLossError as e:
+                    self._remesh(e.keep)
+                    chunks = self.plan_chunks(n - i)
+                    continue
+                chunks.pop(0)
+                if pad:
+                    self.stats["padded_images"] += pad
+                    self._m_padded.inc(pad, **self._mlabels)
+                if steady:
+                    # steady-state call: a call that traced (compiled) would
+                    # poison the learned rates by orders of magnitude
+                    bs = self.bucket_stats.setdefault(
+                        bucket, {"calls": 0, "images": 0, "seconds": 0.0,
+                                 "sumsq_seconds": 0.0, "tainted_calls": 0,
+                                 "tainted_seconds": 0.0})
+                    if retried:
+                        # outcome-tagged: a dispatch that needed transient
+                        # retries is real work but not a healthy run — its
+                        # wall clock stays out of the Table II mean/std/CV
+                        # samples (which are *run-to-run variation of the
+                        # healthy path*, the paper's predictability claim)
+                        bs["tainted_calls"] += 1
+                        bs["tainted_seconds"] += dt
+                        self._m_tainted.inc(bucket=bucket, **self._mlabels)
+                    else:
+                        bs["calls"] += 1
+                        bs["images"] += take
+                        # running first/second moments of the per-call wall
+                        # clock (the paper's Table II mean/std methodology)
+                        # — O(1) state, not a per-call sample list a
+                        # long-lived engine would grow without bound
+                        bs["seconds"] += dt
+                        bs["sumsq_seconds"] += dt * dt
+                        self._m_dispatch.observe(dt, bucket=bucket,
+                                                 **self._mlabels)
+                outs.append(y[:take])
+                i += take
+            self.stats["generate_calls"] += 1
+            self.stats["images"] += n
+            self._m_generate_calls.inc(**self._mlabels)
+            self._m_images.inc(n, **self._mlabels)
         return (np.concatenate(outs, axis=0) if len(outs) != 1
                 else outs[0])
 

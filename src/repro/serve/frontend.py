@@ -31,8 +31,16 @@ The control loop per request:
   typed — never a hang, never a silent drop.
 
 `stats()` reports per-tenant p50/p99/CV over completed-request latency
-plus shed/downgrade/requeue counters — the serving bench's ``slo``
-section is this dict over an offered-load sweep.
+(from the ``frontend.request_latency_seconds`` histogram: O(1) memory
+however long the frontend runs) plus shed/downgrade/requeue counters —
+the serving bench's ``slo`` section is this dict over an offered-load
+sweep.
+
+With the tracer on, the worker numbers each wave as it picks it: a
+request's ``queue_wait`` span ends with ``wave=<n>``, and the
+``wave_dispatch`` span that served it carries the same ``wave``, with the
+engine's ``generate`` and bucket-call spans nested under it by
+``parent``.
 """
 from __future__ import annotations
 
@@ -50,6 +58,10 @@ from .admission import AdmissionController, TenantClass
 from .errors import (AdmissionRejected, DeadlineExceeded, EngineDegraded,
                      EngineError)
 from .scheduler import FP32, EdfScheduler, ServiceModel
+
+# request latency: 40 log-spaced buckets a decade from 10 µs to 100 s, so
+# a percentile from the histogram is within 2.92% of the sample's value
+LATENCY_BUCKETS = obsmetrics.log_buckets(1e-5, 100.0, 40)
 
 
 class _FrontendRequest:
@@ -81,8 +93,7 @@ class _FrontendRequest:
 
 def _tenant_zero() -> Dict[str, object]:
     return {"admitted": 0, "completed": 0, "downgraded": 0, "requeued": 0,
-            "shed_admission": 0, "shed_late": 0, "shed_requeue": 0,
-            "latencies_s": []}
+            "shed_admission": 0, "shed_late": 0, "shed_requeue": 0}
 
 
 class AsyncServeFrontend:
@@ -139,12 +150,8 @@ class AsyncServeFrontend:
             "request outcomes by tenant (labels: tenant, outcome)")
         self._m_latency = self.metrics.histogram(
             "frontend.request_latency_seconds",
-            "submit-to-completion latency (labels: tenant, precision)")
-        self._m_qwait = self.metrics.histogram(
-            "frontend.queue_wait_seconds",
-            "submit-to-wave-pick queue wait (label: tenant)")
-        self._m_qrows = self.metrics.gauge(
-            "frontend.queue_rows", "rows currently queued")
+            "submit-to-completion latency (labels: tenant, precision)",
+            buckets=LATENCY_BUCKETS)
 
         self._model = model if model is not None else ServiceModel()
         for precision, eng in self._engines.items():
@@ -161,6 +168,7 @@ class AsyncServeFrontend:
         self._inflight: List[_FrontendRequest] = []
         self._stop = False
         self._next_rid = 0
+        self._next_wave = 0     # worker thread only
         self._slock = threading.Lock()
         self._requests: Dict[int, _FrontendRequest] = {}
         self._tenant_stats: Dict[str, Dict] = {
@@ -283,7 +291,6 @@ class AsyncServeFrontend:
                 self._requests[req.rid] = req
                 self._tenant_stats[t.name]["admitted"] += 1
             self._m_req.inc(tenant=t.name, outcome="admitted")
-            self._m_qrows.set(queued_rows + req.rows)
             req.qspan = self._tracer.begin("queue_wait", cat="frontend",
                                            rid=req.rid, tenant=t.name,
                                            rows=req.rows)
@@ -359,17 +366,18 @@ class AsyncServeFrontend:
         with self._slock:
             tenants = {}
             for name, st in self._tenant_stats.items():
-                lat = np.asarray(st["latencies_s"], dtype=np.float64)
-                row = {k: v for k, v in st.items() if k != "latencies_s"}
+                row = dict(st)
                 row["shed"] = (st["shed_admission"] + st["shed_late"]
                                + st["shed_requeue"])
-                if lat.size:
-                    mean = float(lat.mean())
+                lat = self._m_latency.merged_summary(tenant=name)
+                if lat["count"]:
                     row.update(
-                        p50_ms=float(np.percentile(lat, 50)) * 1e3,
-                        p99_ms=float(np.percentile(lat, 99)) * 1e3,
-                        mean_ms=mean * 1e3,
-                        cv=float(lat.std() / max(mean, 1e-12)),
+                        p50_ms=self._m_latency.quantile(
+                            0.5, tenant=name) * 1e3,
+                        p99_ms=self._m_latency.quantile(
+                            0.99, tenant=name) * 1e3,
+                        mean_ms=lat["mean"] * 1e3,
+                        cv=lat["cv"],
                     )
                 tenants[name] = row
             remeshes = self._remeshes
@@ -388,7 +396,7 @@ class AsyncServeFrontend:
         }
 
     def reset_stats(self) -> None:
-        """Zero the per-tenant counters/latency samples (offered-load
+        """Zero the per-tenant counters and latency histogram (offered-load
         sweeps measure each load point fresh); capacity estimates and
         pinned plans are kept — they are state, not statistics."""
         with self._slock:
@@ -398,7 +406,6 @@ class AsyncServeFrontend:
         # dicts (engine series are cumulative state and stay)
         self._m_req.reset()
         self._m_latency.reset()
-        self._m_qwait.reset()
 
     def plan_fingerprints(self) -> Dict[str, str]:
         """{"b{batch}/{precision}": stable hash} over every pinned
@@ -444,7 +451,6 @@ class AsyncServeFrontend:
             st["completed"] += 1
             if req.downgraded:
                 st["downgraded"] += 1
-            st["latencies_s"].append(done_t - req.submit_t)
         self._m_req.inc(tenant=req.tenant.name, outcome="completed")
         if req.downgraded:
             self._m_req.inc(tenant=req.tenant.name, outcome="downgraded")
@@ -461,13 +467,13 @@ class AsyncServeFrontend:
                     break
                 wave, precision, sheds = self._pick_wave_locked()
                 self._inflight = list(wave)
-                self._m_qrows.set(sum(r.rows for r in self._queue))
-            picked_t = obsclock.now()
+            wave_id = self._next_wave
+            if wave:
+                self._next_wave += 1
             for req in wave:
-                self._tracer.end(req.qspan, outcome="dispatched")
+                self._tracer.end(req.qspan, outcome="dispatched",
+                                 wave=wave_id)
                 req.qspan = None
-                self._m_qwait.observe(picked_t - req.submit_t,
-                                      tenant=req.tenant.name)
             for req in sheds:
                 self._tracer.end(req.qspan, outcome="shed_late")
                 req.qspan = None
@@ -480,7 +486,7 @@ class AsyncServeFrontend:
             if not wave:
                 continue
             try:
-                self._dispatch_wave(wave, precision)
+                self._dispatch_wave(wave, precision, wave_id)
             except Exception as e:   # worker must never die: that's a hang
                 self._worker_errors.append(e)
                 for req in wave:
@@ -524,24 +530,26 @@ class AsyncServeFrontend:
             self._queue.remove(req)
         return wave, precision, sheds
 
-    def _dispatch_wave(self, wave: List[_FrontendRequest],
-                       precision: str) -> None:
+    def _dispatch_wave(self, wave: List[_FrontendRequest], precision: str,
+                       wave_id: int) -> None:
         eng = self._engines[precision]
         remesh_before = len(eng.fault_stats["remesh_events"])
         retries_before = eng.fault_stats["retries"]
         z = (wave[0].z if len(wave) == 1
              else np.concatenate([r.z for r in wave], axis=0))
+        tr = self._tracer
         t0 = obsclock.now()
         try:
-            imgs = eng.generate(z)
+            with (tr.span("wave_dispatch", cat="frontend", wave=wave_id,
+                          precision=precision, rows=int(len(z)),
+                          reqs=len(wave))
+                  if tr.enabled else obstrace.NULL_SPAN):
+                imgs = eng.generate(z)
         except Exception as err:
             self._check_remesh(eng, remesh_before)
             self._requeue_or_shed(wave, err)
             return
         done_t = obsclock.now()
-        self._tracer.complete("wave_dispatch", t0, done_t, cat="frontend",
-                              precision=precision, rows=int(len(z)),
-                              reqs=len(wave))
         remeshed = self._check_remesh(eng, remesh_before)
         retried = eng.fault_stats["retries"] != retries_before
         if not remeshed and not retried and len(z) <= self._max_bucket:

@@ -15,7 +15,7 @@ from test_fault_serving import TINY, tiny_setup, tmp_cache  # noqa: F401
 
 from repro.obs import clock, trace
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               MetricTypeError)
+                               MetricTypeError, log_buckets)
 from repro.obs.report import table2_rows
 from repro.serve import (AsyncServeFrontend, DcnnServeEngine, EngineConfig,
                          TenantClass)
@@ -60,6 +60,35 @@ def test_histogram_merged_summary_pools_across_labels():
     # exact-match summary unaffected by the sibling series
     assert h.summary(net="x", bucket=2)["count"] == 40
     assert h.label_values("bucket") == ["2", "4"]
+
+
+def test_log_buckets_and_quantile_error_bound():
+    bounds = log_buckets(1e-5, 100.0, 40)
+    assert bounds[0] == pytest.approx(1e-5) and bounds[-1] == pytest.approx(
+        100.0)
+    assert len(bounds) == 281
+    ratios = np.asarray(bounds[1:]) / np.asarray(bounds[:-1])
+    assert np.allclose(ratios, 10 ** (1 / 40))
+    rng = np.random.RandomState(11)
+    samples = rng.lognormal(np.log(3e-3), 0.8, size=2000)
+    h = Histogram("lat", buckets=bounds)
+    for v in samples[:1000]:
+        h.observe(float(v), tenant="a", precision="fp32")
+    for v in samples[1000:]:
+        h.observe(float(v), tenant="a", precision="int8")
+    srt = np.sort(samples)
+    for q in (0.01, 0.5, 0.9, 0.99, 1.0):
+        want = srt[max(0, int(np.ceil(q * len(srt))) - 1)]   # nearest rank
+        got = h.quantile(q, tenant="a")
+        assert abs(got - want) / want <= 10 ** (1 / 80) - 1 + 1e-12, q
+    assert samples.min() <= h.quantile(0.001, tenant="a")
+    assert h.quantile(1.0, tenant="a") <= samples.max()
+    assert h.quantile(0.5, tenant="b") is None
+    # one repeated value is read back exactly (min and max clamp)
+    h2 = Histogram("one", buckets=bounds)
+    for _ in range(5):
+        h2.observe(0.0123)
+    assert h2.quantile(0.5) == pytest.approx(0.0123)
 
 
 def test_histogram_bucket_counts_and_bounds_validation():
@@ -160,7 +189,11 @@ def test_span_nesting_records_in_exit_order():
     assert inner["name"] == "inner" and outer["name"] == "outer"
     assert outer["ts"] <= inner["ts"]
     assert outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"]
-    assert outer["args"] == {"rows": 4}
+    # each span gets an id; the inner one names the outer as its parent
+    assert outer["args"] == {"rows": 4, "id": outer["args"]["id"]}
+    assert inner["args"] == {"id": inner["args"]["id"],
+                             "parent": outer["args"]["id"]}
+    assert inner["args"]["id"] != outer["args"]["id"]
 
 
 def test_span_records_exception_class():
@@ -183,7 +216,9 @@ def test_begin_end_attributes_to_begin_thread():
     worker.join()
     marker, qw = t.events()
     assert qw["tid"] == marker["tid"]    # begin thread, not worker
-    assert qw["args"] == {"rid": 1, "outcome": "dispatched"}
+    assert qw["args"] == {"rid": 1, "outcome": "dispatched",
+                          "id": qw["args"]["id"]}
+    assert qw["args"]["id"] != marker["args"]["id"]
     assert qw["dur"] >= 0
 
 
@@ -193,6 +228,39 @@ def test_ring_buffer_keeps_newest():
         t.instant(f"e{i}")
     assert len(t) == 4
     assert [e["name"] for e in t.events()] == ["e6", "e7", "e8", "e9"]
+
+
+def test_ring_buffer_counts_what_it_drops():
+    t = trace.Tracer(capacity=4, enabled=True)
+    for i in range(3):
+        t.instant(f"e{i}")
+    assert t.dropped == 0
+    for i in range(7):
+        with t.span(f"s{i}"):
+            pass
+    assert len(t) == 4 and t.dropped == 6
+    t.clear()
+    assert t.dropped == 0 and len(t) == 0
+
+
+def test_complete_and_spans_on_other_threads_link_parents():
+    t = trace.Tracer(enabled=True)
+
+    def elsewhere():
+        with t.span("elsewhere"):
+            pass
+
+    with t.span("outer"):
+        t0 = clock.now()
+        t.complete("retro", t0, t0)
+        th = threading.Thread(target=elsewhere)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    by = {e["name"]: e["args"] for e in t.events()}
+    assert by["retro"]["parent"] == by["outer"]["id"]
+    assert "parent" not in by["elsewhere"]   # other thread, no open span
+    assert "parent" not in by["outer"]
 
 
 def test_perfetto_export_round_trip(tmp_path):
@@ -320,11 +388,16 @@ def test_frontend_registry_matches_stats(tmp_cache, tiny_setup):
         lsum = lat.merged_summary(tenant="default")
         assert lsum["count"] == 8
         assert lsum["mean"] == pytest.approx(st["mean_ms"] / 1e3, rel=1e-6)
-        qw = fe.metrics.histogram("frontend.queue_wait_seconds")
-        assert qw.merged_summary(tenant="default")["count"] == 8
+        assert lsum["cv"] == pytest.approx(st["cv"], rel=1e-6)
+        # percentiles come from the histogram, inside the observed range
+        assert st["p50_ms"] == pytest.approx(
+            lat.quantile(0.5, tenant="default") * 1e3)
+        assert (lsum["min"] * 1e3 <= st["p50_ms"] <= st["p99_ms"]
+                <= lsum["max"] * 1e3)
         fe.reset_stats()
         assert req.total() == 0
         assert fe.stats()["tenants"]["default"]["admitted"] == 0
+        assert "p50_ms" not in fe.stats()["tenants"]["default"]
         # engine series are cumulative state, not per-window statistics
         assert fe.metrics.counter("engine.generate_calls").total() > 0
     finally:
@@ -366,3 +439,141 @@ def test_trace_covers_request_lifecycle(tmp_cache, tiny_setup, tmp_path):
     qw = by_name["queue_wait"]
     assert qw["args"]["outcome"] == "dispatched"
     assert qw["ts"] + qw["dur"] <= disp["ts"] + disp["dur"]
+
+
+# ---------------------------------------------------------------------------
+# the engine's bucket call, phase by phase
+# ---------------------------------------------------------------------------
+PHASES = ("dispatch.upload", "dispatch.call", "dispatch.wait",
+          "dispatch.copy_back")
+
+
+def test_traced_generate_splits_each_bucket_call_into_phases(tmp_cache,
+                                                             tiny_setup):
+    """Each bucket call is a ``dispatch b<k>`` span holding upload, call,
+    wait and copy back, in that order, on the same thread, inside it,
+    naming it as parent and not overlapping each other."""
+    params, z, ref = tiny_setup
+    eng = DcnnServeEngine.from_config(
+        EngineConfig(model=TINY, backend="pallas", buckets=(2, 4),
+                     warmup=True), params)
+    zz = np.concatenate([z, z[:2]])          # 6 rows -> one b4, one b2 call
+    tracer = trace.enable(clear=True)
+    try:
+        out = eng.generate(zz)
+    finally:
+        trace.disable()
+    np.testing.assert_allclose(out[:4], ref, rtol=1e-5, atol=1e-5)
+    evs = tracer.events()
+    (gen,) = [e for e in evs if e["name"] == "generate"]
+    calls = [e for e in evs if e["name"].startswith("dispatch b")]
+    assert sorted(c["name"] for c in calls) == ["dispatch b2", "dispatch b4"]
+    for call in calls:
+        assert call["args"]["parent"] == gen["args"]["id"]
+        assert call["args"]["steady"] is True
+        phases = sorted((e for e in evs
+                         if e["args"].get("parent") == call["args"]["id"]),
+                        key=lambda e: e["ts"])
+        assert tuple(e["name"] for e in phases) == PHASES
+        end = call["ts"] + call["dur"]
+        prev_end = call["ts"]
+        for ph in phases:
+            assert ph["tid"] == call["tid"]
+            assert ph["ts"] >= prev_end           # in order, no overlap
+            prev_end = ph["ts"] + ph["dur"]
+            assert prev_end <= end
+            assert ph["cat"] == "engine"
+
+
+def test_untraced_dispatch_stays_one_expression(tmp_cache, tiny_setup,
+                                                monkeypatch):
+    """With tracing off nothing is recorded, the split path is never
+    entered, and no wait sits between the call and the copy back."""
+    from repro.serve import engine as engine_mod
+
+    params, z, ref = tiny_setup
+    eng = DcnnServeEngine.from_config(
+        EngineConfig(model=TINY, backend="pallas", buckets=(4,),
+                     warmup=True), params)
+    tracer = trace.get_tracer()
+    trace.disable()
+    tracer.clear()
+
+    def split_path(*a, **k):
+        raise AssertionError("split path taken with tracing off")
+
+    waits = []
+    monkeypatch.setattr(DcnnServeEngine, "_traced_call", split_path)
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready",
+                        lambda x: waits.append(1) or x)
+    out = eng.generate(z)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    assert waits == []
+    assert len(tracer) == 0 and tracer.dropped == 0
+    assert eng.bucket_stats[4]["calls"] == 1
+
+
+def test_queue_wait_and_wave_dispatch_share_a_wave_id(tmp_cache, tiny_setup):
+    """A request's ``queue_wait`` ends naming the wave that took it; the
+    wave's ``wave_dispatch`` span carries the same id, and the engine's
+    ``generate`` under it names it as parent."""
+    params, z, _ = tiny_setup
+    engines = {"fp32": DcnnServeEngine.from_config(
+        EngineConfig(model=TINY, backend="pallas", buckets=(4,),
+                     warmup=True), params)}
+    fe = AsyncServeFrontend(engines, [TenantClass("default", slo_ms=None)])
+    tracer = trace.enable(clear=True)
+    try:
+        rids = []
+        for _ in range(3):
+            rids.append(fe.submit(z, "default"))
+            fe.result(rids[-1], timeout_s=120)
+    finally:
+        trace.disable()
+        fe.close()
+    evs = tracer.events()
+    waits = {e["args"]["rid"]: e["args"]["wave"] for e in evs
+             if e["name"] == "queue_wait"}
+    waves = {e["args"]["wave"]: e for e in evs
+             if e["name"] == "wave_dispatch"}
+    assert set(waits) == set(rids)
+    assert sorted(waits.values()) == sorted(waves) and len(waves) == 3
+    for e in evs:
+        if e["name"] == "generate":
+            assert e["args"]["parent"] in {w["args"]["id"]
+                                           for w in waves.values()}
+
+
+def test_scoped_spans_land_on_the_profilers_host_plane(tmp_cache,
+                                                       tiny_setup, tmp_path):
+    """With a ``jax.profiler`` trace running, the engine's spans are also
+    profiler annotations: they appear by name on a host plane."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    params, z, _ = tiny_setup
+    eng = DcnnServeEngine.from_config(
+        EngineConfig(model=TINY, backend="pallas", buckets=(4,),
+                     warmup=True), params)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    trace.enable(clear=True)
+    try:
+        eng.generate(z)
+    finally:
+        trace.disable()
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    assert {"generate", "dispatch b4", "dispatch.upload", "dispatch.call",
+            "dispatch.wait", "dispatch.copy_back"} <= names

@@ -101,3 +101,42 @@ def test_sparse_compiles_for_v5e(one_chip, layer, batch):
     _assert_compiles(_deconv2d_sparse_jit.lower(
         *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
         CELEBA_DCNN.layers[layer].activation, False))
+
+
+@pytest.mark.parametrize("kind", ["dense", "int8", "sparse"])
+def test_layer_index_names_the_kernel_for_v5e(one_chip, kind):
+    """A layer's index reaches its kernel's name in the compiled program
+    (``deconv2d_l1_...``), which is how a profile tells the layers apart;
+    the unnamed kernel keeps the bare name."""
+    from repro.kernels.deconv2d.kernel import kernel_name
+
+    g, act = LAYERS[1], CELEBA_DCNN.layers[1].activation
+    dtype = jnp.int8 if kind == "int8" else jnp.float32
+    t = choose_tiles(g, dtype, "pallas_sparse" if kind == "sparse"
+                     else "pallas", batch=1, use_cache=False)
+    x = ((1, g.in_h, g.in_w, g.c_in), dtype)
+    w = ((g.kernel, g.kernel, g.c_in, g.c_out), dtype)
+    c = ((g.c_out,), jnp.float32)
+    tiles = (g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n)
+    if kind == "dense":
+        base = "halo_reverse_loop"
+        args = _shapes(one_chip, x, w, c)
+        lower = lambda layer: _deconv2d_jit.lower(  # noqa: E731
+            *args, *tiles, act, False, layer)
+    elif kind == "int8":
+        base = "int8_halo_reverse_loop"
+        args = _shapes(one_chip, x, w, c, c)
+        lower = lambda layer: _deconv2d_int8_jit.lower(  # noqa: E731
+            *args, *tiles, act, 0.05, False, layer)
+    else:
+        base = "sparse_reverse_loop"
+        wz = np.ones(w[0], np.float32)
+        tables = make_sparse_plan(wz, g.stride, g.padding, t.t_ci, t.t_co)
+        args = _shapes(one_chip, x, w, c,
+                       *((tb.shape, jnp.int32) for tb in tables))
+        lower = lambda layer: _deconv2d_sparse_jit.lower(  # noqa: E731
+            *args, *tiles, act, False, layer)
+    named = lower(1).compile().as_text()
+    assert kernel_name(base, 1) == f"deconv2d_l1_{base}"
+    assert f"deconv2d_l1_{base}" in named
+    assert f"deconv2d_{base}" in lower(None).compile().as_text()
