@@ -81,6 +81,8 @@ def run_async(cfg, params, args):
                   f"downgraded={t['downgraded']} shed={t['shed']} "
                   f"p99={p99}")
         print(f"  pinned plans: {sorted(fe.plan_fingerprints())}")
+        overlapped = fe.metrics.counter("frontend.waves_overlapped").total()
+        print(f"  waves launched behind another wave: {overlapped:.0f}")
     finally:
         fe.close()
 

@@ -18,20 +18,24 @@ Design constraints, in order:
   events; a long soak can stay traced without growing memory.  The
   events it evicts are counted (:attr:`Tracer.dropped`), so a reader can
   tell a complete window from a truncated one.
-* **linked** — every recorded span carries an ``id`` arg, and a span
-  recorded on a thread while a scoped span is open there names it as
-  ``parent``: a request's ``rid`` leads to its wave, the wave to its
-  bucket calls, and each call to its phases.
-* **on the profiler's clock** — while enabled, each scoped span is also
-  entered as a ``jax.profiler.TraceAnnotation`` of the same name, so a
-  profile taken meanwhile holds the program's spans next to the device
-  ops.  JAX is imported on first use only: ``obs`` imports without it.
+* **linked** — every recorded span carries an ``id`` arg and may name
+  a ``parent``: given explicitly, or else the scoped span open on the
+  recording thread.  A request's ``rid`` leads to its wave, the wave to
+  its bucket calls, and each call to its phases.
+* **on the profiler's clock** — while enabled, each scoped span, and
+  each begin/end span begun with ``annotate=True``, is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profile taken
+  meanwhile holds the program's spans next to the device ops.  A span
+  ended on another thread than its own is not annotated: the profiler
+  would place it on the thread that ends it.  JAX is imported on first
+  use only: ``obs`` imports without it.
 
 Three recording styles cover the serve stack's shapes:
 
 * ``with tracer.span("generate", rows=n):`` — scoped work on one thread.
 * ``h = tracer.begin("queue_wait"); ... tracer.end(h)`` — spans that
-  start on one thread (submit) and finish on another (worker).
+  start on one thread (submit) and finish on another (worker), or that
+  overlap on one thread without nesting (launched bucket calls).
 * ``tracer.complete(name, t0, t1)`` — retroactive, for code that already
   timed itself (submit, collect, plan builds).
 """
@@ -53,6 +57,7 @@ class _NullSpan:
     """Shared no-op span/handle returned while tracing is disabled."""
 
     __slots__ = ()
+    id = None
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -127,18 +132,30 @@ class _Span:
 
 
 class SpanHandle:
-    """Explicit begin/end handle; may be ended from a different thread."""
+    """Explicit begin/end handle; may be ended from a different thread.
+    Begun with ``annotate``, it is a profiler annotation of the same name
+    while open."""
 
-    __slots__ = ("name", "cat", "args", "t0", "ident", "tname")
+    __slots__ = ("name", "cat", "args", "t0", "ident", "tname",
+                 "annotation")
 
     def __init__(self, name: str, cat: str, args: dict, t0: float,
-                 ident: int, tname: str) -> None:
+                 ident: int, tname: str, annotate: bool) -> None:
         self.name = name
         self.cat = cat
         self.args = args
         self.t0 = t0
         self.ident = ident
         self.tname = tname
+        self.annotation = None
+        if annotate:
+            self.annotation = _profiler_annotation(name)
+            self.annotation.__enter__()
+
+    @property
+    def id(self) -> int:
+        """The span's id, for spans that name it as ``parent``."""
+        return self.args["id"]
 
 
 class Tracer:
@@ -183,33 +200,49 @@ class Tracer:
             return self._local.stack
 
     def _linked(self, args: dict) -> dict:
-        """``args`` with a fresh span id and the open scoped span as
-        parent."""
+        """``args`` with a fresh span id and, unless it names one, the
+        open scoped span as parent."""
         args["id"] = next(self._ids)
         stack = self._open_spans()
-        if stack:
+        if stack and "parent" not in args:
             args["parent"] = stack[-1]
         return args
 
-    def span(self, name: str, cat: str = "serve", **args: object):
-        """Scoped span; returns a shared null object while disabled."""
+    def span(self, name: str, cat: str = "serve",
+             parent: Optional[int] = None, **args: object):
+        """Scoped span; returns a shared null object while disabled.
+        ``parent`` overrides the scoped span open on this thread."""
         if not self._enabled:
             return _NULL
+        if parent is not None:
+            args["parent"] = parent
         return _Span(self, name, cat, args)
 
-    def begin(self, name: str, cat: str = "serve", **args: object):
-        """Start a span that may be ended from another thread."""
+    def begin(self, name: str, cat: str = "serve",
+              parent: Optional[int] = None, annotate: bool = False,
+              **args: object):
+        """Start a span that may be ended from another thread, or that
+        may overlap others on this one; ``parent`` names its parent.
+        ``annotate`` makes it a profiler annotation too, for a span that
+        ends on the thread that begins it."""
         if not self._enabled:
             return _NULL
         th = threading.current_thread()
-        return SpanHandle(name, cat, {**args, "id": next(self._ids)},
-                          clock.now(), th.ident or 0, th.name)
+        args["id"] = next(self._ids)
+        if parent is not None:
+            args["parent"] = parent
+        return SpanHandle(name, cat, args, clock.now(), th.ident or 0,
+                          th.name, annotate)
 
     def end(self, handle, **extra: object) -> None:
         """Finish a :meth:`begin` handle; attributed to the begin thread."""
-        if handle is None or handle is _NULL or not self._enabled:
+        if handle is None or handle is _NULL:
             return
         t1 = clock.now()
+        if handle.annotation is not None:
+            handle.annotation.__exit__(None, None, None)
+        if not self._enabled:
+            return
         args = dict(handle.args)
         args.update(extra)
         self._record("X", handle.name, handle.cat, handle.t0, t1,

@@ -206,6 +206,81 @@ def shard_aligned_buckets(buckets: Sequence[int], n_shards: int
     return tuple(sorted({up(b) for b in buckets}))
 
 
+class _DeviceClock:
+    """When the last bucket call on one set of devices finished.  Every
+    engine serving on those devices shares it (`_device_clock`): the
+    frontend overlaps waves of its fp32 and int8 engines, and a call that
+    queued behind another engine's call starts its occupancy at that
+    call's finish, as behind one of its own engine's."""
+
+    __slots__ = ("last_finish",)
+
+    def __init__(self):
+        self.last_finish = 0.0
+
+
+_DEVICE_CLOCKS: Dict[frozenset, _DeviceClock] = {}
+
+
+def _device_clock(mesh) -> _DeviceClock:
+    """The shared `_DeviceClock` of ``mesh``'s devices (the default
+    device without a mesh)."""
+    devices = frozenset(mesh.devices.flat if mesh is not None
+                        else jax.devices()[:1])
+    return _DEVICE_CLOCKS.setdefault(devices, _DeviceClock())
+
+
+class _BucketCall:
+    """One launched bucket call: its rows, its images on their way to the
+    host, and what its finish books."""
+
+    __slots__ = ("bucket", "take", "chunk", "y", "out", "error", "t0",
+                 "seconds", "steady", "retried", "span")
+
+    def __init__(self, bucket: int, take: int, chunk: np.ndarray):
+        self.bucket = bucket
+        self.take = take          # useful rows; the rest is padding
+        self.chunk = chunk        # the padded host rows, for a re-run
+        self.y = None             # device images until finished
+        self.out: Optional[np.ndarray] = None
+        self.error: Optional[Exception] = None
+        self.t0 = 0.0             # launch clock
+        self.seconds = 0.0        # occupancy, set at finish
+        self.steady = True        # did not trace (compile)
+        self.retried = False      # needed a transient retry
+        self.span = None
+
+
+class PendingGenerate:
+    """A batch `DcnnServeEngine.launch` put on the device.  `result`
+    returns its images through the engine's `generate`, which finishes
+    its bucket calls in launch order; after it, ``seconds`` is the calls'
+    occupancy (the engine's timing samples) and ``retried`` says whether
+    any call needed a retry or a re-run."""
+
+    def __init__(self, engine: "DcnnServeEngine", z, rows: int, span):
+        self.z = z                # the batch as the caller passed it
+        self.rows = rows
+        self.span = span
+        self.calls: List[_BucketCall] = []
+        self.seconds = 0.0
+        self.retried = False
+        self._engine = engine
+        self._images: Optional[np.ndarray] = None
+        self._error: Optional[BaseException] = None
+
+    def result(self) -> np.ndarray:
+        if self._error is not None:
+            raise self._error
+        if self._images is None:
+            try:
+                self._images = self._engine._answer(self)
+            except BaseException as e:
+                self._error = e
+                raise
+        return self._images
+
+
 class DcnnServeEngine:
     """The paper's inference workload: batched image generation, served
     through compile-once batch buckets.
@@ -252,24 +327,32 @@ class DcnnServeEngine:
       clock).  `from_config` accepts a pre-built/deserialized plan so a
       deployment executes exactly the configuration it validated.
 
-    * **Fault tolerance** — every bucket dispatch runs guarded: an
+    * **Launch / finish** — `launch` uploads each chunk, enqueues its
+      step and the device-to-host copy of its images, and returns; the
+      `PendingGenerate` it returns finishes the calls in order, through
+      `generate`.  A caller that launches the next batch before finishing
+      this one overlaps the host's work with the device's; `generate`
+      alone launches and finishes at once.
+
+    * **Fault tolerance** — every bucket launch runs guarded: an
       optional `dist.inject.FaultInjector` hook fires scripted faults, a
       transient call failure retries with bounded exponential backoff
       (then fails typed as `EngineDegraded`), an optional
-      `dist.fault.Heartbeat` armed around the call records stalls, and a
-      per-bucket `StragglerMonitor` flags steady-state calls slower than
-      ``straggler_factor`` x their EMA.  A detected **device loss**
-      triggers elastic recovery (`_remesh`): shrink onto the surviving
-      prefix via `dist.fault.elastic_mesh`, re-align buckets to the new
-      device count, `reshard_tree` the replicated params, re-plan every
-      bucket (autotune cache hits via plan hashes keep this fast) and
-      ASSERT via `plan.executable_fingerprints` that every per-device
-      batch re-derived the validated plan hash — then re-run the
-      interrupted chunk and keep serving.  `submit` takes a per-request
-      deadline; an expired ticket fails typed (`DeadlineExceeded`) at
-      drain instead of executing stale work, and a drain whose
-      generate() fails restores every ticket to the queue.  All of it is
-      observable through ``fault_stats``.
+      `dist.fault.Heartbeat` armed while calls are in flight records
+      stalls, and a per-bucket `StragglerMonitor` flags steady-state
+      calls slower than ``straggler_factor`` x their EMA.  A detected
+      **device loss** triggers elastic recovery (`_remesh`): shrink onto
+      the surviving prefix via `dist.fault.elastic_mesh`, re-align
+      buckets to the new device count, `reshard_tree` the replicated
+      params, re-plan every bucket (autotune cache hits via plan hashes
+      keep this fast) and ASSERT via `plan.executable_fingerprints` that
+      every per-device batch re-derived the validated plan hash — then
+      re-run the interrupted chunk and keep serving.  Calls in flight at
+      the loss finish first, and a call whose finish raises runs again.
+      `submit` takes a per-request deadline; an expired ticket fails typed
+      (`DeadlineExceeded`) at drain instead of executing stale work, and
+      a drain whose generate() fails restores every ticket to the queue.
+      All of it is observable through ``fault_stats``.
 
     ``trace_counts`` maps bucket -> number of times its generator was
     traced (== compiled); tests pin the no-per-request-recompilation
@@ -469,16 +552,25 @@ class DcnnServeEngine:
         self.fault_injector = fault_injector
         self._stragglers: Dict[int, StragglerMonitor] = {}
         self._dispatches = 0
+        # launched bucket calls not yet finished, in launch order (the
+        # dispatching thread alone touches them), and the last finish on
+        # these devices, by any engine (each call's occupancy starts no
+        # earlier)
+        self._launched: List[_BucketCall] = []
+        self._clock = _device_clock(self.mesh)
+        # launched batches not yet answered, by the id of the array the
+        # caller passed (each pending holds that array), oldest first
+        self._launched_batches: Dict[int, List[PendingGenerate]] = {}
         self.fault_stats = {
             "retries": 0, "transient_failures": 0, "stragglers": 0,
             "heartbeat_fires": 0, "deadline_expired": 0, "shed": 0,
-            "remesh_events": [],
+            "finish_failures": 0, "remesh_events": [],
         }
         self._heartbeat = None
         if config.heartbeat_timeout_s is not None:
             self._heartbeat = Heartbeat(config.heartbeat_timeout_s,
                                         self._on_stall)
-            self._heartbeat.disarm()   # armed per dispatched call only
+            self._heartbeat.disarm()   # armed while a call is in flight
         # plan-build observability: serving must pay planning once per
         # bucket, never per call (bench pins this)
         self.plan_stats = {"builds": 0, "build_seconds": 0.0}
@@ -621,44 +713,40 @@ class DcnnServeEngine:
         if self._heartbeat is not None:
             self._heartbeat.close()
 
-    def _dispatch(self, bucket: int, chunk: np.ndarray):
-        """One guarded bucket dispatch: injector hook, heartbeat armed
-        around the call, bounded retry-with-backoff on transient
-        failures, straggler detection on the steady-state wall clock.
+    def _launch(self, bucket: int, take: int, chunk: np.ndarray,
+                gen_span) -> "_BucketCall":
+        """Launch one guarded bucket call: injector hook, heartbeat armed,
+        bounded retry-with-backoff on transient failures.  The call
+        uploads ``chunk``, enqueues the step and then the device-to-host
+        copy of its images, and returns without waiting for either
+        (`_finish` collects them).
 
-        Returns ``(images, seconds, steady, retried)`` where ``steady``
-        means the call did not trace (compile) and ``retried`` means at
-        least one transient-failure retry preceded the success — only
-        steady samples feed the timing stats and the straggler EMA, and
-        retried ones are tagged so they never mix into the healthy
-        run-to-run CV samples (Table II accounting).  `TransientCallError`
-        is retried up to ``max_retries`` times then raised as
-        `EngineDegraded`; `DeviceLossError` escapes to `generate`, which
-        remeshes.
-
-        With the tracer on, each attempt is a ``dispatch b<bucket>`` span
-        split into its phases (`_traced_call`); off, the call is one
-        expression, with no wait between the call and the copy back."""
+        `TransientCallError` is retried up to ``max_retries`` times then
+        raised as `EngineDegraded`; `DeviceLossError` escapes to
+        `_launch_rows`, which remeshes.  With the tracer on, each attempt
+        is a ``dispatch b<bucket>`` span (`_traced_launch`) under
+        ``gen_span``; off, the launch is three expressions."""
         fn = self._get_fn(bucket)
+        call = _BucketCall(bucket, take, chunk)
         attempts = self.config.max_retries + 1
         for attempt in range(attempts):
             if self._heartbeat is not None:
                 self._heartbeat.arm()
+            traces_before = self.trace_counts.get(bucket, 0)
             try:
-                traces_before = self.trace_counts.get(bucket, 0)
+                # the injector hook sits inside the timed window: an
+                # injected SlowCall is a slow *dispatch*, visible to the
+                # straggler monitor exactly like a real one
+                call.t0 = obsclock.now()
                 if self._tracer.enabled:
-                    y, dt = self._traced_call(fn, bucket, chunk, attempt,
-                                              traces_before)
+                    self._traced_launch(fn, call, attempt, gen_span)
                 else:
-                    # the injector hook sits inside the timed window: an
-                    # injected SlowCall is a slow *dispatch*, visible to
-                    # the straggler monitor exactly like a real one
-                    t0 = obsclock.now()
                     if self.fault_injector is not None:
                         self.fault_injector.before_call(bucket)
-                    y = np.asarray(fn(self.params, jnp.asarray(chunk)))
-                    dt = obsclock.now() - t0
+                    call.y = fn(self.params, jnp.asarray(chunk))
+                    call.y.copy_to_host_async()
             except TransientCallError as e:
+                self._rest_heartbeat()
                 with self._qlock:
                     self.fault_stats["transient_failures"] += 1
                 self._m_fault.inc(event="transient_failures", **self._mlabels)
@@ -676,55 +764,163 @@ class DcnnServeEngine:
                                      attempt=attempt, **self._mlabels)
                 time.sleep(self.config.retry_backoff_s * (2 ** attempt))
                 continue
-            finally:
-                if self._heartbeat is not None:
-                    self._heartbeat.disarm()
-            self._dispatches += 1
-            steady = self.trace_counts.get(bucket, 0) == traces_before
-            retried = attempt > 0
-            if steady and not retried:
-                # a dispatch that needed retries is not a healthy sample:
-                # it must not seed the straggler baseline either
-                mon = self._stragglers.setdefault(
-                    bucket, StragglerMonitor(
-                        factor=self.config.straggler_factor,
-                        warmup_steps=self.config.straggler_warmup))
-                if mon.observe(self._dispatches, dt):
-                    with self._qlock:
-                        self.fault_stats["stragglers"] += 1
-                    self._m_fault.inc(event="stragglers", **self._mlabels)
-                    self._tracer.instant("straggler", cat="fault",
-                                         bucket=bucket, seconds=dt,
-                                         **self._mlabels)
-            return y, dt, steady, retried
+            except BaseException:
+                self._rest_heartbeat()
+                raise
+            call.steady = self.trace_counts.get(bucket, 0) == traces_before
+            call.retried = attempt > 0
+            self._launched.append(call)
+            return call
 
-    def _traced_call(self, fn, bucket: int, chunk: np.ndarray,
-                     attempt: int, traces_before: int):
-        """One attempt of a bucket call as a ``dispatch b<bucket>`` span
-        with four phases inside it: ``dispatch.upload`` (host side of the
-        host-to-device copy), ``dispatch.call`` (until the jitted call
-        returns: the enqueue), ``dispatch.wait`` (until the device is
-        done) and ``dispatch.copy_back`` (device-to-host copy of the
-        ready result).  Returns ``(images, seconds)`` as the untraced
-        path does; the wait costs one more host wake-up."""
+    def _traced_launch(self, fn, call: "_BucketCall", attempt: int,
+                       gen_span) -> None:
+        """One launch attempt as the start of a ``dispatch b<bucket>``
+        span whose parent is ``gen_span`` (the ``generate`` span): the
+        injector hook, then ``dispatch.upload`` (host side of the
+        host-to-device copy) and ``dispatch.call`` (until the jitted call
+        and the device-to-host copy are enqueued).  `_traced_finish` ends
+        it."""
         tr = self._tracer
-        with tr.span(f"dispatch b{bucket}", cat="engine", bucket=bucket,
-                     **self._mlabels) as span:
-            t0 = obsclock.now()
+        span = tr.begin(f"dispatch b{call.bucket}", cat="engine",
+                        parent=getattr(gen_span, "id", None), annotate=True,
+                        bucket=call.bucket, **self._mlabels)
+        try:
             if self.fault_injector is not None:
-                self.fault_injector.before_call(bucket)
-            with tr.span("dispatch.upload", cat="engine"):
-                x = jnp.asarray(chunk)
-            with tr.span("dispatch.call", cat="engine"):
-                y = fn(self.params, x)
-            with tr.span("dispatch.wait", cat="engine"):
-                y = jax.block_until_ready(y)
-            with tr.span("dispatch.copy_back", cat="engine"):
-                y = np.asarray(y)
-            dt = obsclock.now() - t0
-            span.set(steady=self.trace_counts.get(bucket, 0) == traces_before,
-                     retried=attempt > 0)
-        return y, dt
+                self.fault_injector.before_call(call.bucket)
+            with tr.span("dispatch.upload", cat="engine", parent=span.id):
+                x = jnp.asarray(call.chunk)
+            with tr.span("dispatch.call", cat="engine", parent=span.id):
+                call.y = fn(self.params, x)
+                call.y.copy_to_host_async()
+        except BaseException as e:
+            tr.end(span, error=type(e).__name__, retried=attempt > 0)
+            raise
+        call.span = span
+
+    def _finish(self, call: "_BucketCall") -> None:
+        """Bring a launched call's images to the host (``call.out``) or
+        keep what that raised (``call.error``), and book the call.
+
+        Its ``seconds`` is its own occupancy: from the later of its
+        launch and the previous call's finish on these devices (by any
+        engine), to its own finish, so a call that queued behind its
+        predecessor is not a straggler."""
+        try:
+            if call.span is not None:
+                self._traced_finish(call)
+            else:
+                call.out = np.asarray(call.y)
+        except Exception as e:
+            call.error = e
+        finally:
+            call.y = None
+            self._launched.remove(call)
+            t1 = obsclock.now()
+            call.seconds = t1 - max(call.t0, self._clock.last_finish)
+            self._clock.last_finish = t1
+            if self._launched and self._heartbeat is not None:
+                self._heartbeat.arm()     # progress: a fresh silence window
+            else:
+                self._rest_heartbeat()
+        if call.error is not None:
+            with self._qlock:
+                self.fault_stats["finish_failures"] += 1
+            self._m_fault.inc(event="finish_failures", **self._mlabels)
+            self._tracer.instant("finish_failure", cat="fault",
+                                 bucket=call.bucket,
+                                 error=type(call.error).__name__,
+                                 **self._mlabels)
+            return
+        self._book(call)
+
+    def _traced_finish(self, call: "_BucketCall") -> None:
+        """The end of a call's ``dispatch b<bucket>`` span:
+        ``dispatch.wait`` (until the device is done) and
+        ``dispatch.copy_back`` (until its images are on the host); the
+        wait costs one more host wake-up."""
+        tr = self._tracer
+        span = call.span
+        try:
+            with tr.span("dispatch.wait", cat="engine", parent=span.id):
+                y = jax.block_until_ready(call.y)
+            with tr.span("dispatch.copy_back", cat="engine",
+                         parent=span.id):
+                call.out = np.asarray(y)
+        except BaseException as e:
+            tr.end(span, error=type(e).__name__, retried=call.retried)
+            raise
+        tr.end(span, steady=call.steady, retried=call.retried)
+
+    def _rest_heartbeat(self) -> None:
+        """Disarm the heartbeat once no call is in flight: an idle queue
+        is not a stall."""
+        if self._heartbeat is not None and not self._launched:
+            self._heartbeat.disarm()
+
+    def _abandon(self, call: "_BucketCall") -> None:
+        """Drop a launched call whose answer nobody will read."""
+        if call in self._launched:
+            self._launched.remove(call)
+            call.y = None
+            self._tracer.end(call.span, abandoned=True)
+            self._rest_heartbeat()
+
+    def _settle(self) -> None:
+        """Finish every call in flight, in launch order (before a remesh:
+        their answers and samples belong to the mesh that ran them)."""
+        for call in list(self._launched):
+            self._finish(call)
+
+    def _book(self, call: "_BucketCall") -> None:
+        """Per-call accounting of a finished call: straggler monitor,
+        padded rows, and the steady timing samples (Table II)."""
+        bucket, dt = call.bucket, call.seconds
+        self._dispatches += 1
+        pad = bucket - call.take
+        if pad:
+            self.stats["padded_images"] += pad
+            self._m_padded.inc(pad, **self._mlabels)
+        if not call.steady:
+            # a call that traced (compiled) would poison the learned rates
+            # by orders of magnitude
+            return
+        if not call.retried:
+            # a dispatch that needed retries is not a healthy sample: it
+            # must not seed the straggler baseline either
+            mon = self._stragglers.setdefault(
+                bucket, StragglerMonitor(
+                    factor=self.config.straggler_factor,
+                    warmup_steps=self.config.straggler_warmup))
+            if mon.observe(self._dispatches, dt):
+                with self._qlock:
+                    self.fault_stats["stragglers"] += 1
+                self._m_fault.inc(event="stragglers", **self._mlabels)
+                self._tracer.instant("straggler", cat="fault",
+                                     bucket=bucket, seconds=dt,
+                                     **self._mlabels)
+        bs = self.bucket_stats.setdefault(
+            bucket, {"calls": 0, "images": 0, "seconds": 0.0,
+                     "sumsq_seconds": 0.0, "tainted_calls": 0,
+                     "tainted_seconds": 0.0})
+        if call.retried:
+            # outcome-tagged: a dispatch that needed transient retries is
+            # real work but not a healthy run — its wall clock stays out
+            # of the Table II mean/std/CV samples (which are *run-to-run
+            # variation of the healthy path*, the paper's predictability
+            # claim)
+            bs["tainted_calls"] += 1
+            bs["tainted_seconds"] += dt
+            self._m_tainted.inc(bucket=bucket, **self._mlabels)
+        else:
+            bs["calls"] += 1
+            bs["images"] += call.take
+            # running first/second moments of the per-call wall clock
+            # (the paper's Table II mean/std methodology) — O(1) state,
+            # not a per-call sample list a long-lived engine would grow
+            # without bound
+            bs["seconds"] += dt
+            bs["sumsq_seconds"] += dt * dt
+            self._m_dispatch.observe(dt, bucket=bucket, **self._mlabels)
 
     def _remesh(self, keep: int) -> None:
         """Elastic recovery from device loss: shrink onto the surviving
@@ -755,6 +951,7 @@ class DcnnServeEngine:
         self.mesh = elastic_mesh(
             devs[:keep], model_parallel=self.mesh.shape.get("model", 1))
         self.n_devices = data_axis_size(self.mesh, self.rules)
+        self._clock = _device_clock(self.mesh)
         self._param_shardings = tree_shardings(
             self.mesh, self.rules, self.params,
             replicated_specs(self.params))
@@ -855,25 +1052,87 @@ class DcnnServeEngine:
         assert best is not None, (r, self.buckets)
         return best
 
-    # -- synchronous path ----------------------------------------------
-    def generate(self, z: np.ndarray) -> np.ndarray:
-        """z: (B, z_dim) for ANY B: chunked/padded to the bucket set via
-        `plan_chunks`, so no batch size ever triggers a recompile.
+    # -- launch / finish -------------------------------------------------
+    def launch(self, z: np.ndarray,
+               parent: Optional[int] = None) -> "PendingGenerate":
+        """Launch every bucket call of ``z`` (B, z_dim), for ANY B:
+        chunked/padded to the bucket set via `plan_chunks`, so no batch
+        size ever triggers a recompile.  Returns at once, with each
+        call's step and device-to-host copy enqueued;
+        `PendingGenerate.result` finishes them in order.  ``parent`` is
+        the id of the span the ``generate`` span names as its parent, if
+        any.
 
-        Fault path: a transient dispatch failure retries inside
-        `_dispatch`; a detected device loss remeshes onto the survivors
-        (`_remesh`), then the interrupted chunk — plus everything still
-        queued behind it — re-plans against the post-loss bucket set and
-        re-runs, so the call completes on the shrunken mesh instead of
-        raising."""
-        z = np.asarray(z, dtype=self.cfg.dtype)
-        n = z.shape[0]
+        A caller may launch the next batch before finishing this one, so
+        the host's upload of one overlaps the device's step and copy
+        back of the other; calls finish in launch order.
+
+        Fault path: a transient failure retries at launch (`_launch`); a
+        detected device loss finishes whatever is in flight, remeshes
+        onto the survivors (`_remesh`), then re-plans the rows not yet
+        launched against the post-loss bucket set; a call whose finish
+        raises runs its rows again through the guarded path."""
+        pending = self._launch_pending(z, parent)
+        self._launched_batches.setdefault(id(z), []).append(pending)
+        return pending
+
+    def generate(self, z: np.ndarray) -> np.ndarray:
+        """z: (B, z_dim) for ANY B; its images, once every bucket call is
+        back.  A batch `launch` put on the device (this very array) is
+        finished, oldest launch first; any other is launched and
+        finished now.  Every answer the engine hands out, launched or
+        not, passes through here."""
+        queued = self._launched_batches.get(id(z))
+        if queued:
+            pending = queued.pop(0)
+            if not queued:
+                del self._launched_batches[id(z)]
+        else:
+            pending = self._launch_pending(z, None)
+        return self._finish_pending(pending)
+
+    def _answer(self, pending: "PendingGenerate") -> np.ndarray:
+        """``pending``'s images through `generate`: its batch array is
+        put first among the launches of that array.  A `generate` that
+        answers without finishing it (one that computes other arrays)
+        leaves it launched: its calls are then abandoned."""
+        queued = self._launched_batches.get(id(pending.z), [])
+        if pending in queued:
+            queued.remove(pending)
+            queued.insert(0, pending)
+        try:
+            return self.generate(pending.z)
+        finally:
+            if pending in queued:
+                queued.remove(pending)
+                if not queued:
+                    self._launched_batches.pop(id(pending.z), None)
+                for c in pending.calls:
+                    self._abandon(c)
+                self._tracer.end(pending.span, abandoned=True)
+
+    def _launch_pending(self, z, parent: Optional[int]) -> "PendingGenerate":
+        rows = np.asarray(z, dtype=self.cfg.dtype)
         tr = self._tracer
-        with (tr.span("generate", cat="engine", rows=n, **self._mlabels)
-              if tr.enabled else obstrace.NULL_SPAN):
-            outs: List[np.ndarray] = []
-            i = 0
-            chunks = self.plan_chunks(n)
+        span = (tr.begin("generate", cat="engine", parent=parent,
+                         annotate=True, rows=rows.shape[0], **self._mlabels)
+                if tr.enabled else None)
+        pending = PendingGenerate(self, z, rows.shape[0], span)
+        try:
+            pending.calls = self._launch_rows(rows, span)
+        except BaseException as e:
+            tr.end(span, error=type(e).__name__)
+            raise
+        return pending
+
+    def _launch_rows(self, z: np.ndarray, span) -> List["_BucketCall"]:
+        """Launch the chunk plan of ``z``; a device loss settles what is
+        in flight, remeshes, and re-plans the rows not yet launched."""
+        n = z.shape[0]
+        calls: List[_BucketCall] = []
+        i = 0
+        chunks = self.plan_chunks(n)
+        try:
             while chunks:
                 take, bucket = chunks[0]
                 chunk = z[i:i + take]
@@ -883,48 +1142,65 @@ class DcnnServeEngine:
                         [chunk, np.zeros((pad,) + z.shape[1:], z.dtype)],
                         axis=0)
                 try:
-                    y, dt, steady, retried = self._dispatch(bucket, chunk)
+                    call = self._launch(bucket, take, chunk, span)
                 except DeviceLossError as e:
+                    self._settle()
                     self._remesh(e.keep)
                     chunks = self.plan_chunks(n - i)
                     continue
                 chunks.pop(0)
-                if pad:
-                    self.stats["padded_images"] += pad
-                    self._m_padded.inc(pad, **self._mlabels)
-                if steady:
-                    # steady-state call: a call that traced (compiled) would
-                    # poison the learned rates by orders of magnitude
-                    bs = self.bucket_stats.setdefault(
-                        bucket, {"calls": 0, "images": 0, "seconds": 0.0,
-                                 "sumsq_seconds": 0.0, "tainted_calls": 0,
-                                 "tainted_seconds": 0.0})
-                    if retried:
-                        # outcome-tagged: a dispatch that needed transient
-                        # retries is real work but not a healthy run — its
-                        # wall clock stays out of the Table II mean/std/CV
-                        # samples (which are *run-to-run variation of the
-                        # healthy path*, the paper's predictability claim)
-                        bs["tainted_calls"] += 1
-                        bs["tainted_seconds"] += dt
-                        self._m_tainted.inc(bucket=bucket, **self._mlabels)
-                    else:
-                        bs["calls"] += 1
-                        bs["images"] += take
-                        # running first/second moments of the per-call wall
-                        # clock (the paper's Table II mean/std methodology)
-                        # — O(1) state, not a per-call sample list a
-                        # long-lived engine would grow without bound
-                        bs["seconds"] += dt
-                        bs["sumsq_seconds"] += dt * dt
-                        self._m_dispatch.observe(dt, bucket=bucket,
-                                                 **self._mlabels)
-                outs.append(y[:take])
+                calls.append(call)
                 i += take
-            self.stats["generate_calls"] += 1
-            self.stats["images"] += n
-            self._m_generate_calls.inc(**self._mlabels)
-            self._m_images.inc(n, **self._mlabels)
+        except BaseException:
+            for call in calls:
+                self._abandon(call)
+            raise
+        return calls
+
+    def _collect(self, call: "_BucketCall", pending: "PendingGenerate"
+                 ) -> np.ndarray:
+        """A call's useful rows, finishing it if it is still in flight; a
+        call whose finish raised runs its rows again, once, through the
+        guarded path."""
+        if call.out is None and call.error is None:
+            self._finish(call)
+        if call.error is None:
+            pending.seconds += call.seconds
+            return call.out[:call.take]
+        pending.retried = True
+        redo = self._launch_rows(call.chunk[:call.take], pending.span)
+        outs = []
+        try:
+            for c in redo:
+                if c.out is None and c.error is None:
+                    self._finish(c)
+                if c.error is not None:
+                    raise c.error
+                pending.seconds += c.seconds
+                outs.append(c.out[:c.take])
+        except BaseException:
+            for c in redo:
+                self._abandon(c)
+            raise
+        return np.concatenate(outs, axis=0) if len(outs) != 1 else outs[0]
+
+    def _finish_pending(self, pending: "PendingGenerate") -> np.ndarray:
+        tr = self._tracer
+        try:
+            outs = [self._collect(c, pending) for c in pending.calls]
+        except BaseException as e:
+            for c in pending.calls:
+                self._abandon(c)
+            tr.end(pending.span, error=type(e).__name__)
+            raise
+        n = pending.rows
+        pending.retried = pending.retried or any(
+            c.retried for c in pending.calls)
+        self.stats["generate_calls"] += 1
+        self.stats["images"] += n
+        self._m_generate_calls.inc(**self._mlabels)
+        self._m_images.inc(n, **self._mlabels)
+        tr.end(pending.span)
         return (np.concatenate(outs, axis=0) if len(outs) != 1
                 else outs[0])
 

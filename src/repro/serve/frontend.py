@@ -20,15 +20,19 @@ The control loop per request:
   wave's precision: fp32 when it makes the deadline, the pinned int8
   chain when only reduced precision can (graceful degradation; the
   request is tagged ``downgraded``), a typed late shed when nothing can.
-* **dispatch** — one coalesced `generate` per wave; measured wall clocks
-  feed the `ServiceModel` (healthy dispatches only).  A `DeviceLoss`
-  rides the engine's elastic re-bucketing from PR 6 — the interrupted
-  wave completes on the shrunken mesh bit-identically (plan-hash
-  parity), and the frontend scales its capacity estimates down by the
-  lost-device ratio so admission starts shedding at the new capacity.
-  A dispatch failure (`EngineDegraded` after exhausted retries) requeues
-  the wave's requests while their deadlines hold and sheds the rest
-  typed — never a hang, never a silent drop.
+* **dispatch** — one coalesced engine `launch` per wave.  The worker
+  keeps at most two waves launched: after a launch it launches the next
+  wave only if one is queued already, and otherwise finishes the oldest,
+  so one wave's step and copy back hide the next wave's launch whenever
+  traffic keeps the queue full.  Each call's occupancy (as the engine
+  times it) feeds the `ServiceModel` (healthy dispatches only).  A
+  `DeviceLoss` rides the engine's elastic re-bucketing — the
+  interrupted wave completes on the shrunken mesh bit-identically
+  (plan-hash parity), and the frontend scales its capacity estimates
+  down by the lost-device ratio so admission starts shedding at the new
+  capacity.  A dispatch failure (`EngineDegraded` after exhausted
+  retries) requeues that wave's requests while their deadlines hold and
+  sheds the rest typed — never a hang, never a silent drop.
 
 `stats()` reports per-tenant p50/p99/CV over completed-request latency
 (from the ``frontend.request_latency_seconds`` histogram: O(1) memory
@@ -38,16 +42,18 @@ sweep.
 
 With the tracer on, the worker numbers each wave as it picks it: a
 request's ``queue_wait`` span ends with ``wave=<n>``, and the
-``wave_dispatch`` span that served it carries the same ``wave``, with the
-engine's ``generate`` and bucket-call spans nested under it by
-``parent``.
+``wave_dispatch`` span that served it (launch to finish) carries the
+same ``wave`` and ``overlapped``, whether another wave was in flight at
+its launch.  The engine's ``generate`` and bucket-call spans name it as
+``parent``; two waves' spans may overlap on the worker thread.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Deque, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -58,6 +64,11 @@ from .admission import AdmissionController, TenantClass
 from .errors import (AdmissionRejected, DeadlineExceeded, EngineDegraded,
                      EngineError)
 from .scheduler import FP32, EdfScheduler, ServiceModel
+
+# waves launched and not yet finished: one wave's step and device-to-host
+# copy hide the next wave's launch; more only adds memory and latency once
+# the host sets the pace
+MAX_LAUNCHED_WAVES = 2
 
 # request latency: 40 log-spaced buckets a decade from 10 µs to 100 s, so
 # a percentile from the histogram is within 2.92% of the sample's value
@@ -91,6 +102,24 @@ class _FrontendRequest:
         self.qspan = None
 
 
+class _Wave:
+    """A launched wave: its requests, precision and rows, the engine's
+    `PendingGenerate`, its ``wave_dispatch`` span, and the engine's remesh
+    count at launch."""
+
+    __slots__ = ("reqs", "precision", "rows", "remesh_before", "pending",
+                 "span")
+
+    def __init__(self, reqs: List[_FrontendRequest], precision: str,
+                 rows: int, remesh_before: int):
+        self.reqs = reqs
+        self.precision = precision
+        self.rows = rows
+        self.remesh_before = remesh_before
+        self.pending = None
+        self.span = None
+
+
 def _tenant_zero() -> Dict[str, object]:
     return {"admitted": 0, "completed": 0, "downgraded": 0, "requeued": 0,
             "shed_admission": 0, "shed_late": 0, "shed_requeue": 0}
@@ -102,8 +131,9 @@ class AsyncServeFrontend:
     ``engines`` maps precision -> engine; "fp32" is mandatory (the
     undegraded path) and every engine must share one bucket set, so the
     scheduler's per-bucket estimates apply across precisions.  All
-    engine dispatch happens on the single worker thread; callers only
-    touch the queue (thread-safe) and their own request's event."""
+    engine launches and finishes happen on the single worker thread;
+    callers only touch the queue (thread-safe) and their own request's
+    event."""
 
     def __init__(self, engines: Dict[str, "object"],
                  tenants: Sequence[TenantClass], *,
@@ -152,6 +182,9 @@ class AsyncServeFrontend:
             "frontend.request_latency_seconds",
             "submit-to-completion latency (labels: tenant, precision)",
             buckets=LATENCY_BUCKETS)
+        self._m_overlapped = self.metrics.counter(
+            "frontend.waves_overlapped",
+            "waves launched while another was in flight (label: precision)")
 
         self._model = model if model is not None else ServiceModel()
         for precision, eng in self._engines.items():
@@ -174,6 +207,10 @@ class AsyncServeFrontend:
         self._tenant_stats: Dict[str, Dict] = {
             name: _tenant_zero() for name in self._tenants}
         self._remeshes = 0
+        # remesh events already applied to the capacity model, per
+        # precision (worker thread only)
+        self._remeshes_seen = {p: len(e.fault_stats["remesh_events"])
+                               for p, e in self._engines.items()}
         self._worker_errors: List[BaseException] = []
         self._worker = threading.Thread(target=self._run, daemon=True,
                                         name="serve-frontend")
@@ -325,7 +362,7 @@ class AsyncServeFrontend:
         return req.result
 
     def drain(self, timeout_s: Optional[float] = None) -> None:
-        """Block until the queue and in-flight wave are empty."""
+        """Block until the queue and the launched waves are empty."""
         deadline = (None if timeout_s is None
                     else obsclock.now() + timeout_s)
         while True:
@@ -459,14 +496,18 @@ class AsyncServeFrontend:
         req.event.set()
 
     def _run(self) -> None:
+        launched: Deque[_Wave] = collections.deque()   # oldest first
         while True:
+            wave: List[_FrontendRequest] = []
+            sheds: List[_FrontendRequest] = []
             with self._cond:
-                while not self._queue and not self._stop:
+                while not self._queue and not launched and not self._stop:
                     self._cond.wait(timeout=0.05)
-                if self._stop and not self._queue:
+                if self._stop and not self._queue and not launched:
                     break
-                wave, precision, sheds = self._pick_wave_locked()
-                self._inflight = list(wave)
+                if self._queue and len(launched) < MAX_LAUNCHED_WAVES:
+                    wave, precision, sheds = self._pick_wave_locked()
+                    self._inflight.extend(wave)
             wave_id = self._next_wave
             if wave:
                 self._next_wave += 1
@@ -483,28 +524,52 @@ class AsyncServeFrontend:
                     "meet its deadline in queue; shed before dispatch "
                     "(never a post-dispatch DeadlineExceeded)",
                     stage="late"), counter="shed_late")
-            if not wave:
-                continue
-            try:
-                self._dispatch_wave(wave, precision, wave_id)
-            except Exception as e:   # worker must never die: that's a hang
-                self._worker_errors.append(e)
-                for req in wave:
-                    if not req.event.is_set():
-                        self._resolve_error(req, EngineDegraded(
-                            f"frontend worker error: {e!r}"),
-                            counter="shed_requeue")
-            finally:
-                with self._cond:
-                    self._inflight = []
-                    self._cond.notify_all()
+            if wave:
+                # launch, then pick again: the next wave is launched only
+                # if one is queued already, else the oldest finishes
+                w = None
+                try:
+                    w = self._launch_wave(wave, precision, wave_id,
+                                          overlapped=bool(launched))
+                except Exception as e:
+                    self._worker_error(wave, e)
+                if w is None:
+                    self._retire(wave)
+                else:
+                    launched.append(w)
+            elif launched:
+                w = launched.popleft()
+                try:
+                    self._finish_wave(w)
+                except Exception as e:
+                    self._worker_error(w.reqs, e)
+                finally:
+                    self._retire(w.reqs)
+
+    def _worker_error(self, wave: List[_FrontendRequest],
+                      e: Exception) -> None:
+        """An error in the frontend's own code resolves the wave's
+        requests typed: the worker must never die (that's a hang)."""
+        self._worker_errors.append(e)
+        for req in wave:
+            if not req.event.is_set():
+                self._resolve_error(req, EngineDegraded(
+                    f"frontend worker error: {e!r}"),
+                    counter="shed_requeue")
+
+    def _retire(self, wave: List[_FrontendRequest]) -> None:
+        """A wave that is done, answered or not, leaves ``_inflight``."""
+        with self._cond:
+            for req in wave:
+                self._inflight.remove(req)
+            self._cond.notify_all()
 
     def _pick_wave_locked(self):
         """EDF order the queue, shed requests that can no longer make
         their deadlines, and cut one wave: the head request fixes the
         precision, following same-precision requests coalesce until the
-        largest bucket is full (one dispatch per wave keeps per-request
-        latency equal to wave latency — predictable, per Table II)."""
+        largest bucket is full (one bucket call per wave at most, so a
+        wave's launch and finish are one call's)."""
         now = obsclock.now()
         ordered = EdfScheduler.order(self._queue)
         wave: List[_FrontendRequest] = []
@@ -530,53 +595,76 @@ class AsyncServeFrontend:
             self._queue.remove(req)
         return wave, precision, sheds
 
-    def _dispatch_wave(self, wave: List[_FrontendRequest], precision: str,
-                       wave_id: int) -> None:
+    def _launch_wave(self, reqs: List[_FrontendRequest], precision: str,
+                     wave_id: int, overlapped: bool) -> Optional["_Wave"]:
+        """Launch one wave on its precision's engine; ``overlapped`` says
+        another wave is in flight.  Returns the launched wave, or None
+        once a failed launch has requeued or shed its requests."""
         eng = self._engines[precision]
-        remesh_before = len(eng.fault_stats["remesh_events"])
-        retries_before = eng.fault_stats["retries"]
-        z = (wave[0].z if len(wave) == 1
-             else np.concatenate([r.z for r in wave], axis=0))
+        z = (reqs[0].z if len(reqs) == 1
+             else np.concatenate([r.z for r in reqs], axis=0))
+        w = _Wave(reqs, precision, len(z),
+                  len(eng.fault_stats["remesh_events"]))
         tr = self._tracer
-        t0 = obsclock.now()
+        if tr.enabled:
+            w.span = tr.begin("wave_dispatch", cat="frontend", annotate=True,
+                              wave=wave_id, precision=precision,
+                              rows=w.rows, reqs=len(reqs),
+                              overlapped=overlapped)
         try:
-            with (tr.span("wave_dispatch", cat="frontend", wave=wave_id,
-                          precision=precision, rows=int(len(z)),
-                          reqs=len(wave))
-                  if tr.enabled else obstrace.NULL_SPAN):
-                imgs = eng.generate(z)
+            w.pending = eng.launch(z, parent=getattr(w.span, "id", None))
         except Exception as err:
-            self._check_remesh(eng, remesh_before)
-            self._requeue_or_shed(wave, err)
+            self._tracer.end(w.span, error=type(err).__name__)
+            self._check_remesh(precision, w.remesh_before)
+            self._requeue_or_shed(reqs, err)
+            return None
+        if overlapped:
+            self._m_overlapped.inc(precision=precision)
+        return w
+
+    def _finish_wave(self, w: "_Wave") -> None:
+        """Finish a launched wave and complete its requests; a failure
+        requeues or sheds this wave's requests alone."""
+        try:
+            imgs = w.pending.result()
+        except Exception as err:
+            self._tracer.end(w.span, error=type(err).__name__)
+            self._check_remesh(w.precision, w.remesh_before)
+            self._requeue_or_shed(w.reqs, err)
             return
         done_t = obsclock.now()
-        remeshed = self._check_remesh(eng, remesh_before)
-        retried = eng.fault_stats["retries"] != retries_before
-        if not remeshed and not retried and len(z) <= self._max_bucket:
+        self._tracer.end(w.span)
+        remeshed = self._check_remesh(w.precision, w.remesh_before)
+        if (not remeshed and not w.pending.retried
+                and w.rows <= self._max_bucket):
             # healthy dispatch at a known bucket: feed the capacity model
-            # (a wave that rode a remesh or retries is not a healthy
-            # sample — same outcome-tagging rule as engine.bucket_stats)
-            self._model.observe(precision, eng.bucket_for(len(z)),
-                                done_t - t0)
+            # the calls' own occupancy, as the engine times them (a wave
+            # that rode a remesh or retries is not a healthy sample —
+            # same outcome-tagging rule as engine.bucket_stats)
+            eng = self._engines[w.precision]
+            self._model.observe(w.precision, eng.bucket_for(w.rows),
+                                w.pending.seconds)
         ofs = 0
-        for req in wave:
+        for req in w.reqs:
             req.result = imgs[ofs:ofs + req.rows]
             ofs += req.rows
-            self._record_completion(req, precision, done_t)
+            self._record_completion(req, w.precision, done_t)
 
-    def _check_remesh(self, eng, remesh_before: int) -> bool:
-        """Scale capacity estimates down by the lost-device ratio after
-        an elastic remesh: admission must start shedding at the shrunken
-        capacity *now*, not after estimates drift there."""
-        events = eng.fault_stats["remesh_events"]
-        if len(events) == remesh_before:
-            return False
-        for ev in events[remesh_before:]:
+    def _check_remesh(self, precision: str, remesh_before: int) -> bool:
+        """Scale capacity estimates down by the lost-device ratio for each
+        elastic remesh not yet applied: admission must start shedding at
+        the shrunken capacity *now*, not after estimates drift there.
+        True if the engine remeshed since it had ``remesh_before``."""
+        events = self._engines[precision].fault_stats["remesh_events"]
+        seen = self._remeshes_seen[precision]
+        for ev in events[seen:]:
             factor = ev["devices_before"] / max(1, ev["devices_after"])
             self._model.scale(factor)
-        with self._slock:
-            self._remeshes += len(events) - remesh_before
-        return True
+        if len(events) > seen:
+            with self._slock:
+                self._remeshes += len(events) - seen
+            self._remeshes_seen[precision] = len(events)
+        return len(events) != remesh_before
 
     def _requeue_or_shed(self, wave: List[_FrontendRequest],
                          err: Exception) -> None:

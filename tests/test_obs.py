@@ -222,6 +222,20 @@ def test_begin_end_attributes_to_begin_thread():
     assert qw["dur"] >= 0
 
 
+def test_begin_is_a_profiler_annotation_only_when_asked():
+    """A begin/end span ended on another thread (``queue_wait``) is no
+    profiler annotation, which would land on the ending thread's line;
+    one begun with ``annotate`` is, and neither records ``annotate``."""
+    t = trace.Tracer(enabled=True)
+    plain = t.begin("queue_wait", rid=1)
+    marked = t.begin("wave_dispatch", annotate=True, wave=0)
+    assert plain.annotation is None and marked.annotation is not None
+    t.end(marked)
+    t.end(plain)
+    assert [(e["name"], sorted(e["args"])) for e in t.events()] == [
+        ("wave_dispatch", ["id", "wave"]), ("queue_wait", ["id", "rid"])]
+
+
 def test_ring_buffer_keeps_newest():
     t = trace.Tracer(capacity=4, enabled=True)
     for i in range(10):
@@ -488,7 +502,9 @@ def test_traced_generate_splits_each_bucket_call_into_phases(tmp_cache,
 def test_untraced_dispatch_stays_one_expression(tmp_cache, tiny_setup,
                                                 monkeypatch):
     """With tracing off nothing is recorded, the split path is never
-    entered, and no wait sits between the call and the copy back."""
+    entered, no span object is built, and no wait sits between the call
+    and the copy back: the launch is the upload, the call and the copy's
+    enqueue, the finish one ``np.asarray``."""
     from repro.serve import engine as engine_mod
 
     params, z, ref = tiny_setup
@@ -503,7 +519,10 @@ def test_untraced_dispatch_stays_one_expression(tmp_cache, tiny_setup,
         raise AssertionError("split path taken with tracing off")
 
     waits = []
-    monkeypatch.setattr(DcnnServeEngine, "_traced_call", split_path)
+    for name in ("_traced_launch", "_traced_finish"):
+        monkeypatch.setattr(DcnnServeEngine, name, split_path)
+    for name in ("span", "begin", "complete", "instant"):
+        monkeypatch.setattr(trace.Tracer, name, split_path)
     monkeypatch.setattr(engine_mod.jax, "block_until_ready",
                         lambda x: waits.append(1) or x)
     out = eng.generate(z)
