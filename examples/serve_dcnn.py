@@ -55,7 +55,8 @@ def run_async(cfg, params, args):
         params,
         [TenantClass("gold", slo_ms=args.slo_ms, priority=0),
          TenantClass("std", slo_ms=None, priority=1)],
-        precisions=("fp32", "int8"), prime=1)
+        # a tower that is not a deconvolution chain has no int8 path
+        precisions=("fp32",) if cfg.is_graph else ("fp32", "int8"), prime=1)
     try:
         rng = np.random.RandomState(0)
         rids, rejected = [], 0

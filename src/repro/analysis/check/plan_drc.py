@@ -12,7 +12,8 @@ JSON) **without executing anything**:
   factors, `padded_geometry()` / Eq. 5 halo geometry resolvable and
   internally consistent;
 * ``drc.geometry_chain`` — layer i's output extents/channels feed
-  layer i+1's input exactly;
+  layer i+1's input exactly, with a skip join's channels added: the
+  joined output is of an earlier layer and of the same extent;
 * ``drc.input_root``     — the tower's first-layer input (1x1 latent
   root or H×W×C image root) and last-layer output match what the plan's
   declared `repro.workloads` entry expects;
@@ -54,7 +55,7 @@ from .rules import CheckReport, PlanRuleViolation, Severity, rule
 
 KNOWN_BACKENDS = ("pallas", "pallas_sparse", "reverse_loop", "xla")
 TILED_BACKENDS = ("pallas", "pallas_sparse")
-KNOWN_ACTIVATIONS = (None, "relu", "tanh")
+KNOWN_ACTIVATIONS = (None, "relu", "tanh", "leaky_relu")
 _REL_TOL = 1e-9
 
 
@@ -89,7 +90,8 @@ def check_backend(r, plan) -> List[PlanRuleViolation]:
             out.append(r.violation(
                 f"int8 plan streams dtype {l.dtype!r}", layer=i,
                 fix_hint="int8 chains stream int8 between layers"))
-        if plan.backend in TILED_BACKENDS and l.tiles is None:
+        if (plan.backend in TILED_BACKENDS and l.kind == "deconv"
+                and l.tiles is None):
             out.append(r.violation(
                 "tiled backend but no resolved TileChoice", layer=i,
                 fix_hint="re-plan with autotune (or fallback) tiles"))
@@ -180,18 +182,39 @@ def check_tile_alignment(r, plan) -> List[PlanRuleViolation]:
 
 
 @rule("drc.geometry_chain",
-      "layer i's output feeds layer i+1's input exactly")
+      "layer i's output, joined with its skip, feeds layer i+1's input")
 def check_geometry_chain(r, plan) -> List[PlanRuleViolation]:
     out: List[PlanRuleViolation] = []
+    fix = "the layer list was edited after pinning; re-plan from the " \
+          "network config"
     for i in range(len(plan.layers) - 1):
         g, nxt = plan.layers[i].geometry, plan.layers[i + 1].geometry
-        if (g.out_h, g.out_w, g.c_out) != (nxt.in_h, nxt.in_w, nxt.c_in):
+        skip = plan.layers[i + 1].skip
+        c_in = g.c_out
+        if skip is not None:
+            if not 0 <= skip < i:
+                out.append(r.violation(
+                    f"layer {i + 1} joins layer {skip}: a skip joins an "
+                    f"earlier layer than the previous one ({i})",
+                    layer=i + 1, fix_hint=fix))
+                continue
+            s = plan.layers[skip].geometry
+            if (s.out_h, s.out_w) != (g.out_h, g.out_w):
+                out.append(r.violation(
+                    f"layer {i + 1} concatenates layer {skip}'s "
+                    f"{s.out_h}x{s.out_w} output with layer {i}'s "
+                    f"{g.out_h}x{g.out_w}: the joined inputs must have "
+                    "one spatial size", layer=i + 1, fix_hint=fix))
+                continue
+            c_in += s.c_out
+        if (g.out_h, g.out_w, c_in) != (nxt.in_h, nxt.in_w, nxt.c_in):
             out.append(r.violation(
-                f"layer {i} emits {g.out_h}x{g.out_w}x{g.c_out} but "
-                f"layer {i + 1} expects {nxt.in_h}x{nxt.in_w}x{nxt.c_in}",
-                layer=i + 1,
-                fix_hint="the layer list was edited after pinning; "
-                         "re-plan from the network config"))
+                f"layer {i} emits {g.out_h}x{g.out_w}x{c_in}"
+                + (f" with its skip from layer {skip}" if skip is not None
+                   else "")
+                + f" but layer {i + 1} expects "
+                f"{nxt.in_h}x{nxt.in_w}x{nxt.c_in}",
+                layer=i + 1, fix_hint=fix))
     return out
 
 

@@ -29,6 +29,10 @@ import numpy as np
 from .offsets import make_phase_plan, offset_table
 from .tiling import out_size
 
+# leaky_relu's slope for negative inputs (the pix2pix encoders'): shared by
+# the model's layers and the kernels' fused epilogue
+LEAKY_SLOPE = 0.2
+
 
 # ---------------------------------------------------------------------------
 # Literal Algorithm 1 (numpy, instrumented)

@@ -231,6 +231,35 @@ class DeconvGeometry:
 
 
 @dataclasses.dataclass(frozen=True)
+class ConvGeometry(DeconvGeometry):
+    """Static geometry of one strided forward-convolution layer (an
+    encoder layer of an image-to-image tower): the same fields, with the
+    forward convolution's output extent ``(in + 2P - K) // S + 1`` and one
+    MAC per (output pixel, tap, c_in, c_out).  It has no phase plan: the
+    deconvolution kernels never run it."""
+
+    @property
+    def out_h(self) -> int:
+        return (self.in_h + 2 * self.padding - self.kernel) // self.stride + 1
+
+    @property
+    def out_w(self) -> int:
+        return (self.in_w + 2 * self.padding - self.kernel) // self.stride + 1
+
+    @property
+    def macs(self) -> int:
+        return (self.out_h * self.out_w * self.kernel * self.kernel
+                * self.c_in * self.c_out)
+
+    def phase_plan(self) -> PhasePlan:
+        raise TypeError("a forward convolution has no deconvolution "
+                        "phase plan")
+
+    def halo_padding(self) -> Tuple[int, int]:
+        raise TypeError("a forward convolution has no deconvolution halo")
+
+
+@dataclasses.dataclass(frozen=True)
 class DeconvTraffic:
     """Modeled HBM traffic of the halo-streaming kernel for one layer
     (per batch element).  ``in_bytes_per_tile`` is the Eq. 5 window — a

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.dse import TPU_V5E, Device, planning_device, tile_attainable
-from ..core.tiling import LANE, SUBLANE, DeconvGeometry, kernel_vmem_bytes
+from ..core.tiling import (LANE, SUBLANE, ConvGeometry, DeconvGeometry,
+                           kernel_vmem_bytes)
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 # v2: the batch tile t_n joined the schema — both the key format and the
@@ -351,15 +352,18 @@ def network_tiles(
     def out_bytes(i: int) -> Optional[int]:
         return 4 if int8_chain and i == len(geoms) - 1 else None
 
+    # forward-convolution layers run XLA's convolution: no tiles
+    deconvs = [(i, g) for i, g in enumerate(geoms)
+               if not isinstance(g, ConvGeometry)]
     if autotune:
         return {i: choose_tiles(g, dtype, backend=backend, refine=refine,
                                 device=device, batch=batch,
                                 out_dtype_bytes=out_bytes(i))
-                for i, g in enumerate(geoms)}
+                for i, g in deconvs}
     itemsize = np.dtype(dtype).itemsize
     return {i: fallback_tiles(g, itemsize, device.onchip_bytes, batch=batch,
                               out_dtype_bytes=out_bytes(i))
-            for i, g in enumerate(geoms)}
+            for i, g in deconvs}
 
 
 # ---------------------------------------------------------------------------

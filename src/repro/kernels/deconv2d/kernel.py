@@ -25,9 +25,9 @@ This is the paper's FPGA accelerator re-derived for the TPU memory hierarchy:
   static loops; each (tap, phase) contribution is a channel-contraction
   matmul on the MXU with the weight slab held stationary.
 * **Fused epilogue**: bias is the accumulator's initial value (Algorithm 1's
-  initializeToBias) and the activation (relu/tanh) runs in the ``_flush``
-  phase on the f32 accumulator — the generator never materializes a
-  pre-activation layer in HBM.
+  initializeToBias) and the activation (relu/tanh/leaky_relu) runs in the
+  ``_flush`` phase on the f32 accumulator — the generator never
+  materializes a pre-activation layer in HBM.
 
 The accumulator scratch is laid out ``(T_N, T_OH/S, S, T_OW, T_CO)``: phase
 ``(ph, pw)`` accumulates into H-phase slot ``ph`` and every S-th W row from
@@ -44,11 +44,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.deconv import LEAKY_SLOPE
 from ...core.dse import TPU_V5E
 from ...core.offsets import PhasePlan
 from ...core.tiling import LANE, SUBLANE, HaloTile, halo_tile
 
-ACTIVATIONS = (None, "none", "relu", "tanh")
+ACTIVATIONS = (None, "none", "relu", "tanh", "leaky_relu")
 
 # Shared by the dense, int8 and sparse kernels: grid axes (batch, oh, ow,
 # co, ci) with the CI reduction last, and a scoped-VMEM limit twice the
@@ -74,6 +75,8 @@ def apply_activation(y: jax.Array, activation: Optional[str]) -> jax.Array:
         return jnp.maximum(y, 0.0)
     if activation == "tanh":
         return jnp.tanh(y)
+    if activation == "leaky_relu":
+        return jnp.where(y >= 0, y, LEAKY_SLOPE * y)
     return y
 
 

@@ -224,7 +224,8 @@ def deconv2d(
 
     x: (N, IH, IW, CI); w: (K, K, CI, CO); b: (CO,) or None.
     Output: (N, OH, OW, CO), OH = (IH-1)*S + K - 2P.
-    `activation` ("relu"/"tanh"/None) runs fused in the kernel's flush phase.
+    `activation` ("relu"/"tanh"/"leaky_relu"/None) runs fused in the
+    kernel's flush phase.
 
     **Plan fast path** — ``plan`` is a `repro.plan.DeconvPlan`: stride,
     padding, the full tile assignment and the fused activation all come

@@ -11,26 +11,53 @@ The generator's deconvolution layers run through a selectable backend:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..core.deconv import deconv2d_reverse_loop, deconv2d_zero_insertion
-from ..core.tiling import DeconvGeometry
+from ..core.deconv import (LEAKY_SLOPE, deconv2d_reverse_loop,
+                           deconv2d_zero_insertion)
+from ..core.tiling import ConvGeometry, DeconvGeometry
 from ..dist.context import constrain
 from . import nn
 
 
+BN_EPS = 1e-5        # eval-mode BatchNorm's epsilon (PyTorch's default)
+
+
 @dataclasses.dataclass(frozen=True)
 class DeconvLayerCfg:
+    """One layer of a tower: a transposed convolution (``kind="deconv"``)
+    or a strided forward convolution (``kind="conv"``), ``c_in`` counting
+    every channel it reads.
+
+    A layer reads the previous layer's output (the tower's input for the
+    first); with ``skip`` it reads ``concat(out[skip], previous)`` along
+    the channels, the earlier layer's output first (a U-Net join).
+    ``in_activation`` applies to that input, ``norm="batch"`` is an
+    eval-mode BatchNorm after the convolution (running statistics,
+    applied as written in the step) and ``activation``
+    the output nonlinearity after it: relu | tanh | leaky_relu | None."""
+
     c_in: int
     c_out: int
     kernel: int
     stride: int
     padding: int
-    activation: str  # relu | tanh
+    activation: Optional[str]  # relu | tanh | leaky_relu | None
+    kind: str = "deconv"
+    norm: Optional[str] = None
+    skip: Optional[int] = None
+    in_activation: Optional[str] = None
+
+    @property
+    def is_plain(self) -> bool:
+        """A chain layer: a deconvolution reading the previous output."""
+        return (self.kind, self.norm, self.skip, self.in_activation) == (
+            "deconv", None, None, None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +72,10 @@ class DcnnConfig:
     layers[0].c_in``.  Every consumer of the config — kernels, plans,
     quantization, serving — keys off `input_shape`/`geometries()`, so
     the two roots share one execution surface (see `repro.workloads`).
+
+    ``dot_precision`` is the precision of every convolution's products:
+    None takes JAX's default (on a TPU, float32 operands in one bfloat16
+    MXU pass), ``"highest"`` float32 products (`jax.lax.Precision`).
     """
 
     name: str
@@ -54,6 +85,7 @@ class DcnnConfig:
     layers: Tuple[DeconvLayerCfg, ...]
     dtype: str = "float32"
     in_hw: int = 1
+    dot_precision: Optional[str] = None
 
     @property
     def jdtype(self):
@@ -77,11 +109,44 @@ class DcnnConfig:
             return (self.z_dim,)
         return (self.in_hw, self.in_hw, self.in_c)
 
+    @property
+    def is_graph(self) -> bool:
+        """True when some layer is not a plain chain deconvolution: a
+        forward convolution, a BatchNorm, a skip join or an input
+        activation (the U-Net generators)."""
+        return not all(l.is_plain for l in self.layers)
+
     def geometries(self) -> List[DeconvGeometry]:
+        """Per layer, its geometry: a `ConvGeometry` for ``kind="conv"``.
+        A layer's input extent is the previous layer's output extent; a
+        skip join must bring an output of the same extent and the
+        channels must add up to ``c_in`` (checked here)."""
         h = w = self.in_hw
-        out = []
-        for l in self.layers:
-            g = DeconvGeometry(h, w, l.c_in, l.c_out, l.kernel, l.stride, l.padding)
+        out: List[DeconvGeometry] = []
+        for i, l in enumerate(self.layers):
+            if l.kind not in ("deconv", "conv"):
+                raise ValueError(f"{self.name} layer {i}: unknown kind "
+                                 f"{l.kind!r}")
+            c_in = l.c_in
+            if l.skip is not None:
+                if not 0 <= l.skip < i - 1:
+                    raise ValueError(
+                        f"{self.name} layer {i}: skip {l.skip} must name "
+                        "an earlier layer than the previous one")
+                s = out[l.skip]
+                if (s.out_h, s.out_w) != (h, w):
+                    raise ValueError(
+                        f"{self.name} layer {i}: skip from layer {l.skip} "
+                        f"is {s.out_h}x{s.out_w}, the previous output "
+                        f"{h}x{w}")
+                c_in -= s.c_out
+            prev_c = out[-1].c_out if out else self.in_c
+            if out and c_in != prev_c:
+                raise ValueError(
+                    f"{self.name} layer {i} reads {l.c_in} channels; its "
+                    f"inputs bring {l.c_in - c_in + prev_c}")
+            geom = ConvGeometry if l.kind == "conv" else DeconvGeometry
+            g = geom(h, w, l.c_in, l.c_out, l.kernel, l.stride, l.padding)
             out.append(g)
             h, w = g.out_h, g.out_w
         return out
@@ -114,6 +179,51 @@ CELEBA_DCNN = DcnnConfig(
 )
 
 
+def unet_config(name: str, img_hw: int = 256, in_c: int = 3, out_c: int = 3,
+                ngf: int = 64, num_downs: int = 8,
+                dtype: str = "float32") -> DcnnConfig:
+    """The pix2pix U-Net generator (Isola et al., arXiv:1611.07004, 6.1.1;
+    ``UnetGenerator`` of the authors' ``models/networks.py``) in eval mode.
+
+    ``num_downs`` strided K=4, S=2, P=1 convolutions halve the image down
+    to 1x1, widening ``ngf`` x 1, 2, 4, 8, 8, ...; each but the first and
+    the innermost carries a BatchNorm, and each but the innermost ends in
+    LeakyReLU(0.2), whose output is also what the skip carries.  As many
+    transposed convolutions double it back, each reading ReLU of its
+    input, and each after the first joining the mirror encoder's output:
+    BatchNorm on all but the last, tanh on the last.  Layers ``l0`` ..
+    ``l<num_downs-1>`` are the encoder, the rest the decoder.
+
+    Its products are float32 (``dot_precision="highest"``): with one
+    bfloat16 pass per dot, any float32-level difference (a sum's order)
+    grows through the sixteen roundings to about one bfloat16 rounding of
+    the output, so two sound programs would differ as much as a program
+    computed in bfloat16 differs from either."""
+    widths = [ngf * min(2 ** k, 8) for k in range(num_downs)]
+    layers = []
+    c = in_c
+    for k, w in enumerate(widths):
+        inner = k == num_downs - 1
+        layers.append(DeconvLayerCfg(
+            c, w, 4, 2, 1, None if inner else "leaky_relu", kind="conv",
+            norm=None if k == 0 or inner else "batch"))
+        c = w
+    for j in range(num_downs):
+        last = j == num_downs - 1
+        layers.append(DeconvLayerCfg(
+            c if j == 0 else 2 * widths[num_downs - 1 - j],
+            out_c if last else widths[num_downs - 2 - j], 4, 2, 1,
+            "tanh" if last else None, norm=None if last else "batch",
+            skip=None if j == 0 else num_downs - 1 - j,
+            in_activation="relu"))
+    return DcnnConfig(name=name, z_dim=0, img_hw=img_hw, img_c=out_c,
+                      layers=tuple(layers), dtype=dtype, in_hw=img_hw,
+                      dot_precision="highest")
+
+
+PIX2PIX_UNET256 = unet_config("pix2pix-unet256")
+
+
 # ---------------------------------------------------------------------------
 # Generator
 # ---------------------------------------------------------------------------
@@ -138,6 +248,9 @@ def tower_input(cfg: DcnnConfig, x: jax.Array) -> jax.Array:
 
 
 def generator_init(key, cfg: DcnnConfig):
+    """Weights ``{"l<i>": {"w": (K, K, C_in, C_out), "b": (C_out,)}}``;
+    a layer with ``norm="batch"`` also holds its running statistics and
+    affine, ``"bn": {"gamma", "beta", "mean", "var"}``, at identity."""
     ks = jax.random.split(key, len(cfg.layers))
     p: Dict[str, Any] = {}
     s: Dict[str, Any] = {}
@@ -150,7 +263,56 @@ def generator_init(key, cfg: DcnnConfig):
             "b": jnp.zeros((l.c_out,), cfg.jdtype),
         }
         s[f"l{i}"] = {"w": (None, None, "cin", "cout"), "b": ("cout",)}
+        if l.norm == "batch":
+            one = jnp.ones((l.c_out,), cfg.jdtype)
+            zero = jnp.zeros((l.c_out,), cfg.jdtype)
+            p[f"l{i}"]["bn"] = {"gamma": one, "beta": zero, "mean": zero,
+                                "var": one}
+            s[f"l{i}"]["bn"] = {k: ("cout",) for k in p[f"l{i}"]["bn"]}
     return p, s
+
+
+def activate(x: jax.Array, activation: Optional[str]) -> jax.Array:
+    """A layer's nonlinearity, outside a fused kernel epilogue."""
+    if activation is None or activation == "none":
+        return x
+    if activation == "relu":
+        return jax.nn.relu(x)
+    if activation == "tanh":
+        return jnp.tanh(x)
+    if activation == "leaky_relu":
+        return jax.nn.leaky_relu(x, LEAKY_SLOPE)
+    raise ValueError(f"unknown activation {activation!r}")
+
+
+def batch_norm(x: jax.Array, bn) -> jax.Array:
+    """Eval-mode BatchNorm over the last axis, from running statistics."""
+    scale = bn["gamma"] / jnp.sqrt(bn["var"] + BN_EPS)
+    return (x - bn["mean"]) * scale + bn["beta"]
+
+
+def normalize(y: jax.Array, q) -> jax.Array:
+    """A layer's BatchNorm on its convolution's output ``y``, taken
+    without bias: the bias, then ``batch_norm``."""
+    return batch_norm(y + q["b"], q["bn"])
+
+
+def conv2d(x: jax.Array, w: jax.Array, b: jax.Array, stride: int,
+           padding: int) -> jax.Array:
+    """A strided forward convolution, NHWC x HWIO, plus bias: XLA's
+    convolution, the kernel of every ``kind="conv"`` layer."""
+    return jax.lax.conv_general_dilated(
+        x, w, (stride, stride), ((padding, padding), (padding, padding)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+
+
+def dot_precision(cfg: DcnnConfig):
+    """The context a tower's products run in: ``cfg.dot_precision`` where
+    set (`jax.default_matmul_precision`, which reaches XLA's convolutions
+    and the Pallas kernels' dots alike), else the caller's."""
+    if cfg.dot_precision is None:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision(cfg.dot_precision)
 
 
 def _tile_kwargs(t) -> Dict[str, int]:
@@ -182,11 +344,17 @@ def generator_apply(
     layer index -> precomputed `make_sparse_plan` result for
     backend="pallas_sparse" (see serve.DcnnServeEngine).
 
-    On the pallas backends each layer's kernel is named with its index
-    (``deconv2d_l<i>_...``, see `kernels.deconv2d.kernel.kernel_name`),
-    and its bias + activation run fused in the kernel's flush phase, so
-    the chain never materializes a pre-activation layer in HBM; the other
-    backends apply the activation separately.
+    On the pallas backends each deconvolution layer's kernel is named
+    with its index (``deconv2d_l<i>_...``, see
+    `kernels.deconv2d.kernel.kernel_name`), and its bias + activation run
+    fused in the kernel's flush phase, so the chain never materializes a
+    pre-activation layer in HBM; the other backends apply the activation
+    separately.  Forward-convolution layers run XLA's convolution on
+    every backend, under the scope ``conv2d_l<i>``; a skip join runs
+    under ``skip_concat_l<i>``.  A layer with a BatchNorm (``"bn"``
+    running statistics) applies it after the convolution, then its
+    activation, outside the kernel.  Every product is taken at
+    ``cfg.dot_precision``.
     ``return_intermediates=True`` additionally returns the list of
     per-layer *inputs* (the tensors quantization calibrates against —
     see quant.calibrate): ``(images, [x_0, ..., x_{L-1}])``.
@@ -201,46 +369,68 @@ def generator_apply(
     x = tower_input(cfg, z).astype(cfg.jdtype)
     x = constrain(x, "batch", None, None, None)
     inters = []
-    for i, l in enumerate(cfg.layers):
-        if return_intermediates:
-            inters.append(x)
-        w, b = p[f"l{i}"]["w"], p[f"l{i}"]["b"]
-        lp = plan.layers[i] if plan is not None else None
-        fused = backend in ("pallas", "pallas_sparse")
-        if backend == "reverse_loop":
-            x = deconv2d_reverse_loop(x, w, b, l.stride, l.padding)
-        elif backend == "xla":
-            x = deconv2d_zero_insertion(x, w, b, l.stride, l.padding)
-        elif backend == "pallas":
-            from ..kernels.deconv2d import deconv2d
-            from ..kernels.deconv2d.ops import suppress_tile_warnings
-            if lp is not None:
-                x = deconv2d(x, w, b, plan=lp, layer=i)
+    outs = []   # every layer's output, for the skip joins
+    with dot_precision(cfg):
+        for i, l in enumerate(cfg.layers):
+            if l.skip is not None:
+                with jax.named_scope(f"skip_concat_l{i}"):
+                    x = jnp.concatenate([outs[l.skip], x], axis=-1)
+            x = activate(x, l.in_activation)
+            if return_intermediates:
+                inters.append(x)
+            q = p[f"l{i}"]
+            lp = plan.layers[i] if plan is not None else None
+            # the kernel epilogue adds the bias and applies the activation
+            # unless a BatchNorm has to come between them (`normalize`)
+            normed = "bn" in q
+            w = q["w"]
+            b = jnp.zeros_like(q["b"]) if normed else q["b"]
+            fused = backend in ("pallas", "pallas_sparse") and not normed
+            act = l.activation if fused else None
+            if l.kind == "conv":
+                fused = True   # its BatchNorm and activation run in its scope
+                with jax.named_scope(f"conv2d_l{i}"):
+                    x = conv2d(x, w, b, l.stride, l.padding)
+                    if normed:
+                        x = normalize(x, q)
+                    x = activate(x, l.activation)
+            elif backend == "reverse_loop":
+                x = deconv2d_reverse_loop(x, w, b, l.stride, l.padding)
+            elif backend == "xla":
+                x = deconv2d_zero_insertion(x, w, b, l.stride, l.padding)
+            elif backend == "pallas":
+                from ..kernels.deconv2d import deconv2d
+                from ..kernels.deconv2d.ops import suppress_tile_warnings
+                if lp is not None:
+                    x = deconv2d(x, w, b, plan=lp, layer=i)
+                else:
+                    # supported legacy override surface: the expansion into
+                    # tile kwargs is ours, not the user's — don't warn
+                    with suppress_tile_warnings():
+                        x = deconv2d(
+                            x, w, b, l.stride, l.padding,
+                            activation=act, layer=i,
+                            **_tile_kwargs((tile_overrides or {}).get(i)))
+            elif backend == "pallas_sparse":
+                from ..kernels.deconv2d.ops import suppress_tile_warnings
+                from ..kernels.deconv2d_sparse import deconv2d_sparse
+                if lp is not None:
+                    x = deconv2d_sparse(x, w, b, plan=lp, layer=i)
+                else:
+                    with suppress_tile_warnings():
+                        x = deconv2d_sparse(
+                            x, w, b, l.stride, l.padding,
+                            activation=act, layer=i,
+                            plan=(sparse_plans or {}).get(i),
+                            **_tile_kwargs((tile_overrides or {}).get(i)))
             else:
-                # supported legacy override surface: the expansion into
-                # tile kwargs is ours, not the user's — don't warn
-                with suppress_tile_warnings():
-                    x = deconv2d(
-                        x, w, b, l.stride, l.padding,
-                        activation=l.activation, layer=i,
-                        **_tile_kwargs((tile_overrides or {}).get(i)))
-        elif backend == "pallas_sparse":
-            from ..kernels.deconv2d.ops import suppress_tile_warnings
-            from ..kernels.deconv2d_sparse import deconv2d_sparse
-            if lp is not None:
-                x = deconv2d_sparse(x, w, b, plan=lp, layer=i)
-            else:
-                with suppress_tile_warnings():
-                    x = deconv2d_sparse(
-                        x, w, b, l.stride, l.padding,
-                        activation=l.activation, layer=i,
-                        plan=(sparse_plans or {}).get(i),
-                        **_tile_kwargs((tile_overrides or {}).get(i)))
-        else:
-            raise ValueError(backend)
-        if not fused:
-            x = jnp.tanh(x) if l.activation == "tanh" else jax.nn.relu(x)
-        x = constrain(x, "batch", None, None, None)
+                raise ValueError(backend)
+            if not fused:
+                if normed:
+                    x = normalize(x, q)
+                x = activate(x, l.activation)
+            x = constrain(x, "batch", None, None, None)
+            outs.append(x)
     if return_intermediates:
         return x, inters
     return x
@@ -327,11 +517,8 @@ def critic_init(key, cfg: DcnnConfig):
 def critic_apply(p, cfg: DcnnConfig, x: jax.Array) -> jax.Array:
     n_conv = len([k for k in p if k.startswith("c")])
     for i in range(n_conv):
-        x = jax.lax.conv_general_dilated(
-            x, p[f"c{i}"]["w"], (2, 2), ((1, 1), (1, 1)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        ) + p[f"c{i}"]["b"]
-        x = jax.nn.leaky_relu(x, 0.2)
+        x = activate(conv2d(x, p[f"c{i}"]["w"], p[f"c{i}"]["b"], 2, 1),
+                     "leaky_relu")
         x = constrain(x, "batch", None, None, None)
     x = x.reshape(x.shape[0], -1)
     return nn.dense(p["head"], x)[:, 0]

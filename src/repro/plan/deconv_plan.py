@@ -24,7 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from ..core.tiling import DeconvGeometry
+from ..core.tiling import ConvGeometry, DeconvGeometry
 from ..kernels.autotune import TileChoice
 
 # Bump when the serialized plan layout changes incompatibly.  Loaders
@@ -72,6 +72,20 @@ class DeconvPlan:
       * ``sparse_tables`` — the host-built (ci_idx, valid, tap_mask)
                             schedule (excluded from equality/hash; its
                             ``sparse_digest`` stands in for it).
+
+    The layer's place in a U-Net-like tower (hashed only when set, so a
+    chain's plans keep their hashes):
+      * ``kind``          — "deconv" (the Pallas kernels) or "conv" (a
+                            strided forward convolution run by XLA, with
+                            a `core.tiling.ConvGeometry` and no tiles);
+      * ``norm``          — "batch": the layer's eval-mode BatchNorm,
+                            applied after the kernel from its running
+                            statistics;
+      * ``skip``          — the earlier layer whose output is
+                            concatenated before the previous one's;
+      * ``in_activation`` — applied to the (joined) input;
+      * ``dot_precision`` — the precision of the layer's products
+                            (`models.dcnn.DcnnConfig.dot_precision`).
     """
 
     geometry: DeconvGeometry
@@ -86,6 +100,11 @@ class DeconvPlan:
     tiles: Optional[TileChoice] = None
     sparse_tables: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = \
         dataclasses.field(default=None, compare=False, repr=False)
+    kind: str = "deconv"
+    norm: Optional[str] = None
+    skip: Optional[int] = None
+    in_activation: Optional[str] = None
+    dot_precision: Optional[str] = None
 
     # -- hashing --------------------------------------------------------
     def request_dict(self, scope: str = "full") -> Dict[str, Any]:
@@ -116,6 +135,17 @@ class DeconvPlan:
             "tiles": (self.tiles.as_kwargs()
                       if self.tiles is not None else None),
         })
+        d.update(self.topology())
+        return d
+
+    def topology(self) -> Dict[str, Any]:
+        """The fields that place the layer in a non-chain tower, those
+        that are set (empty for a chain layer)."""
+        d: Dict[str, Any] = {} if self.kind == "deconv" else {
+            "kind": self.kind}
+        for name in ("norm", "skip", "in_activation", "dot_precision"):
+            if getattr(self, name) is not None:
+                d[name] = getattr(self, name)
         return d
 
     def stable_hash(self, scope: str = "full") -> str:
@@ -186,8 +216,10 @@ class DeconvPlan:
         tables = d.get("sparse_tables")
         if tables is not None:
             tables = tuple(np.asarray(a, np.int32) for a in tables)
+        kind = d.get("kind", "deconv")
+        geom = ConvGeometry if kind == "conv" else DeconvGeometry
         plan = cls(
-            geometry=DeconvGeometry(**d["geometry"]),
+            geometry=geom(**d["geometry"]),
             batch=int(d["batch"]),
             dtype=str(d["dtype"]),
             backend=str(d["backend"]),
@@ -203,6 +235,11 @@ class DeconvPlan:
                                  if k in TileChoice.__dataclass_fields__})
                    if tiles is not None else None),
             sparse_tables=tables,
+            kind=kind,
+            norm=d.get("norm"),
+            skip=d.get("skip"),
+            in_activation=d.get("in_activation"),
+            dot_precision=d.get("dot_precision"),
         )
         if tables is not None and plan.sparse_digest is not None:
             got = _sparse_digest(tables)
@@ -231,6 +268,10 @@ def build_layer_plan(
     device=None,
     sparse_table_cache: Optional[Dict] = None,
     sparse_cache_key=None,
+    norm: Optional[str] = None,
+    skip: Optional[int] = None,
+    in_activation: Optional[str] = None,
+    dot_precision: Optional[str] = None,
 ) -> DeconvPlan:
     """Resolve one layer's `DeconvPlan` (tiles via the DSE autotuner).
 
@@ -241,15 +282,21 @@ def build_layer_plan(
     which key by layer index.  The memo is only consulted when the caller
     names a ``sparse_cache_key`` (an object identity would be reused by
     the allocator and could serve another weight set's schedule).
-    Non-tiled backends ("reverse_loop", "xla") get a plan with
-    ``tiles=None``."""
+    Non-tiled backends ("reverse_loop", "xla") and forward-convolution
+    layers (a `ConvGeometry`: XLA's convolution on every backend) get a
+    plan with ``tiles=None``.  ``norm``, ``skip``, ``in_activation`` and
+    ``dot_precision`` place the layer in its tower (`DeconvPlan`)."""
     from ..core.dse import planning_device
 
     device = planning_device() if device is None else device
     dtype_name = np.dtype(dtype).name
-    if backend not in ("pallas", "pallas_sparse"):
+    topology = dict(kind="conv" if isinstance(geom, ConvGeometry)
+                    else "deconv", norm=norm, skip=skip,
+                    in_activation=in_activation, dot_precision=dot_precision)
+    if (backend not in ("pallas", "pallas_sparse")
+            or topology["kind"] == "conv"):
         return DeconvPlan(geometry=geom, batch=batch, dtype=dtype_name,
-                          backend=backend, activation=activation)
+                          backend=backend, activation=activation, **topology)
     if tiles is None:
         from ..kernels.autotune import choose_tiles, fallback_tiles
 
@@ -283,4 +330,5 @@ def build_layer_plan(
         activation=activation, out_scale=out_scale,
         out_dtype_bytes=out_dtype_bytes, quant=quant,
         sparse_digest=digest, tiles=tiles, sparse_tables=sparse_tables,
+        **topology,
     )

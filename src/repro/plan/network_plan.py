@@ -49,11 +49,14 @@ class NetworkPlan:
 
     # -- executor-facing views -----------------------------------------
     def tile_overrides(self) -> Optional[Dict[int, Any]]:
-        """Per-layer TileChoice map (what generator_apply consumes), or
-        None for backends without tile factors."""
-        if any(l.tiles is None for l in self.layers):
+        """Per-layer TileChoice map (what generator_apply consumes) of the
+        deconvolution layers, or None for backends without tile factors
+        (forward-convolution layers never have any)."""
+        deconvs = [(i, l) for i, l in enumerate(self.layers)
+                   if l.kind == "deconv"]
+        if any(l.tiles is None for _, l in deconvs):
             return None
-        return {i: l.tiles for i, l in enumerate(self.layers)}
+        return {i: l.tiles for i, l in deconvs}
 
     def sparse_plans(self) -> Optional[Dict[int, tuple]]:
         """Per-layer zero-skip schedules for backend="pallas_sparse"."""
@@ -86,11 +89,17 @@ class NetworkPlan:
             raise ValueError(
                 f"plan '{self.name}' has {len(self.layers)} layers; "
                 f"{cfg.name} has {len(geoms)}")
-        for i, (g, l) in enumerate(zip(geoms, self.layers)):
+        for i, (g, l, c) in enumerate(zip(geoms, self.layers, cfg.layers)):
             if g != l.geometry:
                 raise ValueError(
                     f"plan layer {i} geometry {l.geometry} does not match "
                     f"{cfg.name} layer {i} geometry {g}")
+            want = (c.norm, c.skip, c.in_activation, cfg.dot_precision)
+            if (l.norm, l.skip, l.in_activation, l.dot_precision) != want:
+                raise ValueError(
+                    f"plan layer {i} topology {l.topology()} does not "
+                    f"match {cfg.name} layer {i} (norm, skip, "
+                    f"in_activation, dot_precision) {want}")
 
     def verify_sparse_tables(self, params) -> None:
         """Fail loudly when a pinned pallas_sparse plan's zero-skip
@@ -268,6 +277,10 @@ def build_network_plan(
             "backend='pallas_sparse' planning needs params: the zero-skip "
             "schedule is compiled from the static pruned weights")
     geoms = list(cfg.geometries())
+    if cfg.is_graph and (precision != "fp32" or backend == "pallas_sparse"):
+        raise ValueError(
+            f"{cfg.name} is not a chain of deconvolutions: it plans at "
+            "precision='fp32' on the pallas, xla or reverse_loop backend")
     if precision == "int8" and quant_cfg is None:
         if params is None:
             raise ValueError(
@@ -292,7 +305,9 @@ def build_network_plan(
             batch=batch,
             dtype=dtype,
             backend=backend,
-            activation=l.activation,
+            # a BatchNorm comes between the convolution and its
+            # activation: the kernel's epilogue then stops at the bias
+            activation=None if l.norm else l.activation,
             out_scale=(quant_cfg.out_scale(i) if int8_chain else None),
             # the int8 chain's final epilogue emits f32 images while every
             # intermediate layer re-quantizes to int8 (matches the
@@ -309,6 +324,10 @@ def build_network_plan(
             device=device,
             sparse_table_cache=sparse_table_cache,
             sparse_cache_key=i,
+            norm=l.norm,
+            skip=l.skip,
+            in_activation=l.in_activation,
+            dot_precision=cfg.dot_precision,
         ))
     from ..workloads import workload_name_for
 

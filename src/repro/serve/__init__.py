@@ -6,7 +6,7 @@ from .config import EngineConfig
 from .engine import (DcnnServeEngine, Request, ServeEngine, pow2_buckets,
                      shard_aligned_buckets)
 from .errors import (AdmissionRejected, DeadlineExceeded, EngineDegraded,
-                     EngineError)
+                     EngineError, PrecisionUnsupported)
 from .frontend import AsyncServeFrontend
 from .scheduler import EdfScheduler, ServiceModel
 
@@ -16,4 +16,5 @@ __all__ = [
     "AsyncServeFrontend", "TenantClass", "AdmissionController",
     "EdfScheduler", "ServiceModel",
     "AdmissionRejected", "DeadlineExceeded", "EngineDegraded", "EngineError",
+    "PrecisionUnsupported",
 ]

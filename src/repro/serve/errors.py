@@ -40,3 +40,11 @@ class EngineDegraded(EngineError):
     onto, or post-remesh re-planning that did not re-derive the
     validated executables.  The queue is intact — pending tickets stay
     pending and a later drain retries them."""
+
+
+class PrecisionUnsupported(EngineError, ValueError):
+    """The model has no path at the requested precision: a tower that is
+    not a chain of deconvolutions (a U-Net's forward convolutions,
+    BatchNorms and skip joins) serves in float32 only, and an int8
+    engine for it is refused at construction rather than served some
+    other way."""

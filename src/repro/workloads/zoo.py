@@ -1,6 +1,7 @@
 """Built-in workloads: the two paper WGAN generators plus the edge
 workloads the paper motivates DCNN inference with — an ESPCN/FSRCNN-style
-x2 super-resolution head and a denoising autoencoder decoder.
+x2 super-resolution head, a denoising autoencoder decoder, and the pix2pix
+U-Net image-to-image generator.
 
 The SR head maps a 14x14 low-res digit to its 28x28 reconstruction:
 stride-1 feature extraction / nonlinear mapping stages followed by one
@@ -19,10 +20,12 @@ from __future__ import annotations
 import numpy as np
 
 from ..data.synthetic import digit_images
-from ..models.dcnn import CELEBA_DCNN, MNIST_DCNN, DcnnConfig, DeconvLayerCfg
+from ..models.dcnn import (CELEBA_DCNN, MNIST_DCNN, PIX2PIX_UNET256,
+                           DcnnConfig, DeconvLayerCfg)
 from .registry import Workload, register
 
-__all__ = ["SR_X2", "DAE_DENOISE", "SR", "DENOISE", "MNIST", "CELEBA"]
+__all__ = ["SR_X2", "DAE_DENOISE", "SR", "DENOISE", "MNIST", "CELEBA",
+           "PIX2PIX_UNET"]
 
 
 # ---------------------------------------------------------------------------
@@ -125,4 +128,28 @@ CELEBA = register(Workload(
     cfg=CELEBA_DCNN,
     kind="generative",
     description="paper Fig.4 CelebA WGAN-GP generator (z100 -> 64x64x3)",
+))
+
+
+# ---------------------------------------------------------------------------
+# pix2pix U-Net generator: 256x256x3 image -> 256x256x3 image
+# ---------------------------------------------------------------------------
+def _unet_pairs(seed: int, n: int):
+    """(grey input, colour target) pairs at the generator's 256x256: a
+    colourisation task, the target three independent stroke images as
+    its channels and the input their mean in every channel."""
+    hw = PIX2PIX_UNET256.img_hw
+    y = np.concatenate([digit_images(seed + c, n, hw=hw) for c in range(3)],
+                       axis=-1)
+    x = np.repeat(y.mean(axis=-1, keepdims=True), 3, axis=-1)
+    return x, y
+
+
+PIX2PIX_UNET = register(Workload(
+    name="pix2pix-unet256",
+    cfg=PIX2PIX_UNET256,
+    kind="supervised",
+    description="pix2pix U-Net generator unet_256 (256x256x3 -> 256x256x3)",
+    aliases=("unet256", "pix2pix"),
+    pair_fn=_unet_pairs,
 ))

@@ -19,7 +19,7 @@ from repro.kernels.deconv2d.int8 import _deconv2d_int8_jit
 from repro.kernels.deconv2d.ops import _deconv2d_jit
 from repro.kernels.deconv2d_sparse.ops import (_deconv2d_sparse_jit,
                                                make_sparse_plan)
-from repro.models.dcnn import CELEBA_DCNN
+from repro.models.dcnn import CELEBA_DCNN, PIX2PIX_UNET256, dot_precision
 
 LAYERS = CELEBA_DCNN.geometries()
 LAST = len(LAYERS) - 1
@@ -64,6 +64,25 @@ def test_dense_f32_compiles_for_v5e(one_chip, layer, batch):
     _assert_compiles(_deconv2d_jit.lower(
         *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n,
         CELEBA_DCNN.layers[layer].activation, False))
+
+
+@pytest.mark.parametrize("layer", (14, 15))
+def test_unet_decoder_compiles_for_v5e(one_chip, layer):
+    """The U-Net's two largest decoder kernels at its served bucket 16:
+    d7 (64x64x256 -> 128x128x64) and the output layer y (128x128x128 ->
+    256x256x3), the largest inputs any cell gives the kernel, with float32
+    products (the U-Net's ``dot_precision``)."""
+    g = PIX2PIX_UNET256.geometries()[layer]
+    assert (g.in_h, g.c_in) == ((64, 256) if layer == 14 else (128, 128))
+    t = choose_tiles(g, jnp.float32, "pallas", batch=16, use_cache=False)
+    args = _shapes(one_chip,
+                   ((16, g.in_h, g.in_w, g.c_in), jnp.float32),
+                   ((g.kernel, g.kernel, g.c_in, g.c_out), jnp.float32),
+                   ((g.c_out,), jnp.float32))
+    with dot_precision(PIX2PIX_UNET256):
+        _assert_compiles(_deconv2d_jit.lower(
+            *args, g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co,
+            t.t_n, PIX2PIX_UNET256.layers[layer].activation, False, layer))
 
 
 @pytest.mark.parametrize("batch", BUCKETS)
