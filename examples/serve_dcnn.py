@@ -84,6 +84,9 @@ def run_async(cfg, params, args):
         print(f"  pinned plans: {sorted(fe.plan_fingerprints())}")
         overlapped = fe.metrics.counter("frontend.waves_overlapped").total()
         print(f"  waves launched behind another wave: {overlapped:.0f}")
+        folded = fe.metrics.counter("engine.tap_folded_layers").total()
+        print(f"  tap-folded deconvolution layers over the plans built: "
+              f"{folded:.0f}")
     finally:
         fe.close()
 

@@ -133,6 +133,15 @@ SUBLANE = 8
 LANE = 128
 
 
+def tap_fold_group(k: int, t_co: int) -> int:
+    """Taps of a ``k`` x ``k`` kernel whose products one lane-wide dot of
+    the dense kernel yields at once for an output-channel tile of
+    ``t_co``: as many as fit a lane (``LANE // t_co``), at most all
+    ``k * k``.  1 is the per-tap form, which a tile a lane wide keeps:
+    there a fold saves no MXU pass."""
+    return max(1, min(k * k, LANE // t_co))
+
+
 def tiled_bytes(shape: Tuple[int, ...], itemsize: int) -> int:
     """VMEM bytes of a block once its last two dims are padded to whole
     (sublane, lane) tiles — an 8-channel f32 block occupies 128 lanes."""
@@ -159,6 +168,11 @@ def kernel_vmem_bytes(
     by the Pallas pipeline (x2); the 4-byte accumulator scratch (f32 for
     the dense/sparse kernels, int32 for the int8 kernel) is single, and
     one phase's partial sum plus one tap slice live as in-kernel values.
+    A float layer that `tap_fold_group` folds also holds one slab's dot
+    of the whole window, ``(T_N * T_IH * T_IW, LANE)`` f32 scratch, and
+    slices its taps' blocks, not the input's: it counts the larger of the
+    two forms' temporaries, as the sparse kernel, which shares this model
+    and keeps the per-tap form, may take the same tile.
     ``t_n`` is the batch tile: each grid program owns ``t_n`` images' halo
     windows / output blocks (the weight slab is batch-stationary).
     ``dtype_bytes`` is the streamed element width (1 for the int8 kernel);
@@ -178,6 +192,10 @@ def kernel_vmem_bytes(
     rows = t_n * (t_oh // s) * (t_ow // s)
     tmp_bytes = (tiled_bytes((rows, t_co), 4)
                  + tiled_bytes((rows, t_ci), dtype_bytes))
+    if dtype_bytes != 1 and tap_fold_group(k, t_co) > 1:
+        window = t_n * ht_h.extent * ht_w.extent
+        tmp_bytes = max(tmp_bytes, tiled_bytes((window, LANE), 4)
+                        + 2 * tiled_bytes((rows, t_co), 4))
     return (2 * (x_bytes + w_bytes + v_bytes + y_bytes) + acc_bytes
             + tmp_bytes)
 

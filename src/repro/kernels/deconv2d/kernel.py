@@ -29,6 +29,16 @@ This is the paper's FPGA accelerator re-derived for the TPU memory hierarchy:
   ``_flush`` phase on the f32 accumulator — the generator never
   materializes a pre-activation layer in HBM.
 
+* **Tap fold** for thin outputs (``T_CO`` at most half a lane): a dot's
+  output columns are the MXU's columns, so a per-tap
+  ``(rows, T_CI) @ (T_CI, T_CO)`` dot with ``T_CO`` = 8 uses 8 of its 128.  Every tap of a tile reads the same
+  halo window, only at another offset, so the kernel multiplies first and
+  shifts after: `tap_fold_group` taps' weights sit side by side in one
+  lane-wide slab (`fold_tap_weights`), one dot of the whole window
+  yields their products at once, and each phase adds each tap's
+  ``T_CO``-lane block at the tap's static offset.  The products and
+  addends are the per-tap form's; only the order of the f32 sums differs.
+
 The accumulator scratch is laid out ``(T_N, T_OH/S, S, T_OW, T_CO)``: phase
 ``(ph, pw)`` accumulates into H-phase slot ``ph`` and every S-th W row from
 ``pw`` (a strided 32-bit store), so the final phase reassembly is a
@@ -37,7 +47,7 @@ leading-dim reshape and the block keeps the output's (T_OW, T_CO) tiling.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.deconv import LEAKY_SLOPE
 from ...core.dse import TPU_V5E
 from ...core.offsets import PhasePlan
-from ...core.tiling import LANE, SUBLANE, HaloTile, halo_tile
+from ...core.tiling import (LANE, SUBLANE, HaloTile, halo_tile,
+                            tap_fold_group)
 
 ACTIVATIONS = (None, "none", "relu", "tanh", "leaky_relu")
 
@@ -140,25 +151,55 @@ def check_mosaic_tiles(ht_w: HaloTile, n_tiles_w: int, t_ci: int, cip: int,
         raise ValueError("tiles Mosaic cannot block: " + "; ".join(bad))
 
 
+def tap_order(plan: PhasePlan) -> List[Tuple[int, int, int, int, int, int]]:
+    """Every ``(ph, pw, kh, dh, kw, dw)`` tap, phase by phase: the order of
+    the folded weight slab's lane blocks and of the folded kernel's sums."""
+    return [(ph, pw, kh, dh, kw, dw)
+            for ph in range(plan.stride) for pw in range(plan.stride)
+            for kh, dh in plan.taps[ph] for kw, dw in plan.taps[pw]]
+
+
+def fold_tap_weights(w: jax.Array, plan: PhasePlan, t_co: int) -> jax.Array:
+    """Lay padded ``(K, K, CIp, COp)`` weights out for the folded kernel:
+    ``(CIp, n_co * n_groups * LANE)``.  Output-channel tile ``c`` holds
+    ``n_groups`` lane-wide slabs; slab ``j`` holds taps ``j*g .. j*g+g-1``
+    of `tap_order` (``g`` = `tap_fold_group`) side by side, ``t_co``
+    lanes each, zero-padded to the lane."""
+    k, _, cip, cop = w.shape
+    g = tap_fold_group(k, t_co)
+    taps = tap_order(plan)
+    n_groups = -(-len(taps) // g)
+    wt = jnp.stack([w[kh, kw] for _, _, kh, _, kw, _ in taps])
+    wt = jnp.pad(wt, ((0, n_groups * g - len(taps)), (0, 0), (0, 0)))
+    # (group, tap, ci, co tile, t_co) -> (ci, co tile, group, tap * t_co)
+    wt = wt.reshape(n_groups, g, cip, cop // t_co, t_co)
+    wt = wt.transpose(2, 3, 0, 1, 4).reshape(
+        cip, cop // t_co, n_groups, g * t_co)
+    wt = jnp.pad(wt, ((0, 0), (0, 0), (0, 0), (0, LANE - g * t_co)))
+    return wt.reshape(cip, cop // t_co * n_groups * LANE)
+
+
 def _deconv2d_kernel(
     x_ref,      # (T_N, T_IH, T_IW, T_CI)  VMEM halo windows
-    w_ref,      # (K, K, T_CI, T_CO)       VMEM (batch-stationary)
+    w_ref,      # (K, K, T_CI, T_CO), folded (T_CI, n_groups * LANE)
     b_ref,      # (1, T_CO)                VMEM
     o_ref,      # (T_N, T_OH, T_OW, T_CO)  VMEM
     acc_ref,    # (T_N, T_OH/S, S, T_OW, T_CO) f32 scratch
-    *,
+    *fold_ref,  # folded: (T_N, T_IH, T_IW, LANE) f32 scratch, a slab's dot
     plan: PhasePlan,
     ht_h: HaloTile,
     ht_w: HaloTile,
     t_oh: int,
     t_ow: int,
+    group: int,
     n_ci_tiles: int,
     activation: Optional[str],
     out_dtype,
 ):
     s = plan.stride
     th, tw = t_oh // s, t_ow // s
-    t_n = x_ref.shape[0]
+    t_n, ihw, iww, t_ci = x_ref.shape
+    t_co = acc_ref.shape[4]
     ci_idx = pl.program_id(4)
 
     @pl.when(ci_idx == 0)
@@ -168,28 +209,55 @@ def _deconv2d_kernel(
             b_ref[0].astype(jnp.float32), acc_ref.shape
         )
 
-    t_ci = x_ref.shape[3]
-    t_co = w_ref.shape[3]
-    # Loop interchange (enhancement 2): taps outermost, weight slab stationary
-    # across both the phase loops AND the T_N batch images — each tap matmul
-    # contracts over T_N*th*tw rows (the batch-fused MXU fill).
-    for ph in range(s):
-        for pw in range(s):
-            acc = jnp.zeros((t_n * th * tw, t_co), dtype=jnp.float32)
-            for kh, dh in plan.taps[ph]:
-                for kw, dw in plan.taps[pw]:
-                    # static halo-local rows: the window already starts at
-                    # this tile's minimum displacement.
-                    r0 = ht_h.local_offset(dh)
-                    c0 = ht_w.local_offset(dw)
-                    xs = x_ref[:, r0:r0 + th, c0:c0 + tw, :]
-                    acc = acc + jnp.dot(
-                        xs.reshape(t_n * th * tw, t_ci),
-                        w_ref[kh, kw],
-                        preferred_element_type=jnp.float32,
-                    )
-            acc_ref[:, :, ph, pl.ds(pw, tw, stride=s), :] += acc.reshape(
-                t_n, th, tw, t_co)
+    def add_phase(ph, pw, acc):
+        # phase (ph, pw): H-phase slot ph, every S-th W row from pw
+        acc_ref[:, :, ph, pl.ds(pw, tw, stride=s), :] += acc.reshape(
+            t_n, th, tw, t_co)
+
+    if group == 1:
+        # Loop interchange (enhancement 2): taps outermost, weight slab
+        # stationary across both the phase loops AND the T_N batch images —
+        # each tap matmul contracts over T_N*th*tw rows (the batch-fused
+        # MXU fill).
+        for ph in range(s):
+            for pw in range(s):
+                acc = jnp.zeros((t_n * th * tw, t_co), dtype=jnp.float32)
+                for kh, dh in plan.taps[ph]:
+                    for kw, dw in plan.taps[pw]:
+                        # static halo-local rows: the window already starts
+                        # at this tile's minimum displacement.
+                        r0 = ht_h.local_offset(dh)
+                        c0 = ht_w.local_offset(dw)
+                        xs = x_ref[:, r0:r0 + th, c0:c0 + tw, :]
+                        acc = acc + jnp.dot(
+                            xs.reshape(t_n * th * tw, t_ci),
+                            w_ref[kh, kw],
+                            preferred_element_type=jnp.float32,
+                        )
+                add_phase(ph, pw, acc)
+    else:
+        # Tap fold: one dot of the whole window per slab of `group` taps,
+        # then each tap's t_co-lane block of it at the tap's offset.
+        (y_ref,) = fold_ref
+        x = x_ref[...].reshape(t_n * ihw * iww, t_ci)
+        taps = tap_order(plan)
+        phase, acc = None, None
+        for j in range(0, len(taps), group):
+            y_ref[...] = jnp.dot(
+                x, w_ref[:, j // group * LANE:(j // group + 1) * LANE],
+                preferred_element_type=jnp.float32,
+            ).reshape(t_n, ihw, iww, LANE)
+            for m, (ph, pw, _, dh, _, dw) in enumerate(taps[j:j + group]):
+                if (ph, pw) != phase:
+                    if phase is not None:
+                        add_phase(*phase, acc)
+                    phase, acc = (ph, pw), None
+                r0 = ht_h.local_offset(dh)
+                c0 = ht_w.local_offset(dw)
+                ys = y_ref[:, r0:r0 + th, c0:c0 + tw,
+                           m * t_co:(m + 1) * t_co]
+                acc = ys if acc is None else acc + ys
+        add_phase(*phase, acc)
 
     @pl.when(ci_idx == n_ci_tiles - 1)
     def _flush():
@@ -222,9 +290,13 @@ def deconv2d_pallas_call(
     interpret: bool = False,
     layer: Optional[int] = None,
 ) -> jax.Array:
+    """The dense kernel over host-padded operands: per tap, named
+    ``halo_reverse_loop``, or, where `tap_fold_group` folds ``t_co``, with
+    the weights laid out by `fold_tap_weights`, named ``halo_tapfold``."""
     n, ihp, iwp, cip = x_padded.shape
     k = w.shape[0]
     cop = w.shape[3]
+    group = tap_fold_group(k, t_co)
     s = plan.stride
     assert t_oh % s == 0 and t_ow % s == 0, "tiles must be stride-aligned"
     assert cip % t_ci == 0 and cop % t_co == 0
@@ -247,19 +319,27 @@ def deconv2d_pallas_call(
         ht_w=ht_w,
         t_oh=t_oh,
         t_ow=t_ow,
+        group=group,
         n_ci_tiles=n_ci,
         activation=activation,
         out_dtype=x_padded.dtype,
     )
+    scratch = [pltpu.VMEM((t_n, t_oh // s, s, t_ow, t_co), jnp.float32)]
+    if group == 1:
+        w_spec = pl.BlockSpec((k, k, t_ci, t_co),
+                              lambda nb, oh, ow, co, ci: (0, 0, ci, co))
+    else:
+        w = fold_tap_weights(w, plan, t_co)
+        w_spec = pl.BlockSpec((t_ci, w.shape[1] // (cop // t_co)),
+                              lambda nb, oh, ow, co, ci: (ci, co))
+        scratch.append(pltpu.VMEM((t_n, ht_h.extent, ht_w.extent, LANE),
+                                  jnp.float32))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             x_halo_blockspec(ht_h, ht_w, t_ci, t_n, n_tiles_w, n_ci),
-            pl.BlockSpec(
-                (k, k, t_ci, t_co),
-                lambda nb, oh, ow, co, ci: (0, 0, ci, co),
-            ),
+            w_spec,
             pl.BlockSpec((1, t_co), lambda nb, oh, ow, co, ci: (0, co)),
         ],
         out_specs=pl.BlockSpec(
@@ -267,10 +347,9 @@ def deconv2d_pallas_call(
             lambda nb, oh, ow, co, ci: (nb, oh, ow, co),
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohp, owp, cop), x_padded.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((t_n, t_oh // s, s, t_ow, t_co), jnp.float32)
-        ],
+        scratch_shapes=scratch,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        name=kernel_name("halo_reverse_loop", layer),
+        name=kernel_name("halo_reverse_loop" if group == 1
+                         else "halo_tapfold", layer),
     )(x_padded, w, b)

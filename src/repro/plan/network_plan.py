@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from ..core.tiling import tap_fold_group
 from .deconv_plan import (PLAN_SCHEMA_VERSION, DeconvPlan, PlanSchemaError,
                           build_layer_plan)
 
@@ -57,6 +58,16 @@ class NetworkPlan:
         if any(l.tiles is None for _, l in deconvs):
             return None
         return {i: l.tiles for i, l in deconvs}
+
+    def tap_folded_layers(self) -> int:
+        """Deconvolution layers the dense float kernel runs tap-folded:
+        those whose output-channel tile `core.tiling.tap_fold_group`
+        folds.  The int8 and zero-skip kernels fold none."""
+        if self.backend != "pallas" or self.precision != "fp32":
+            return 0
+        return sum(1 for l in self.layers
+                   if l.kind == "deconv" and l.tiles is not None
+                   and tap_fold_group(l.geometry.kernel, l.tiles.t_co) > 1)
 
     def sparse_plans(self) -> Optional[Dict[int, tuple]]:
         """Per-layer zero-skip schedules for backend="pallas_sparse"."""

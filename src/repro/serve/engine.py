@@ -533,6 +533,10 @@ class DcnnServeEngine:
         self._m_copy_back_bytes = self.metrics.counter(
             "engine.copy_back_bytes",
             "device-to-host bytes of the bucket calls' images")
+        self._m_tap_folded = self.metrics.counter(
+            "engine.tap_folded_layers",
+            "deconvolution layers of each bucket's plan that the dense "
+            "kernel runs tap-folded, counted at the plan's build")
         self._m_devices = self.metrics.gauge(
             "engine.device_count", "devices serving this engine")
         self._m_devices.set(self.n_devices, **self._mlabels)
@@ -642,12 +646,14 @@ class DcnnServeEngine:
                 sparse_table_cache=self._sparse_plan_memo,
             )
             dt = obsclock.now() - t0
+            folded = self.plans[bucket].tap_folded_layers()
             self.plan_stats["builds"] += 1
             self.plan_stats["build_seconds"] += dt
             self._m_plan_build.observe(dt, bucket=bucket, **self._mlabels)
+            self._m_tap_folded.inc(folded, bucket=bucket, **self._mlabels)
             self._tracer.complete(f"plan_build b{bucket}", t0, t0 + dt,
                                   cat="engine", bucket=bucket,
-                                  **self._mlabels)
+                                  tap_folded=folded, **self._mlabels)
         return self.plans[bucket]
 
     def _get_fn(self, bucket: int) -> Callable:

@@ -122,14 +122,17 @@ def test_sparse_compiles_for_v5e(one_chip, layer, batch):
         CELEBA_DCNN.layers[layer].activation, False))
 
 
-@pytest.mark.parametrize("kind", ["dense", "int8", "sparse"])
+@pytest.mark.parametrize("kind", ["dense", "dense_thin", "int8", "sparse"])
 def test_layer_index_names_the_kernel_for_v5e(one_chip, kind):
     """A layer's index reaches its kernel's name in the compiled program
     (``deconv2d_l1_...``), which is how a profile tells the layers apart;
-    the unnamed kernel keeps the bare name."""
+    the unnamed kernel keeps the bare name.  The dense kernel of a thin
+    output layer (the 3-channel image, whose tile folds its taps) is
+    ``halo_tapfold``."""
     from repro.kernels.deconv2d.kernel import kernel_name
 
-    g, act = LAYERS[1], CELEBA_DCNN.layers[1].activation
+    layer = LAST if kind == "dense_thin" else 1
+    g, act = LAYERS[layer], CELEBA_DCNN.layers[layer].activation
     dtype = jnp.int8 if kind == "int8" else jnp.float32
     t = choose_tiles(g, dtype, "pallas_sparse" if kind == "sparse"
                      else "pallas", batch=1, use_cache=False)
@@ -137,8 +140,8 @@ def test_layer_index_names_the_kernel_for_v5e(one_chip, kind):
     w = ((g.kernel, g.kernel, g.c_in, g.c_out), dtype)
     c = ((g.c_out,), jnp.float32)
     tiles = (g.stride, g.padding, t.t_oh, t.t_ow, t.t_ci, t.t_co, t.t_n)
-    if kind == "dense":
-        base = "halo_reverse_loop"
+    if kind in ("dense", "dense_thin"):
+        base = "halo_tapfold" if kind == "dense_thin" else "halo_reverse_loop"
         args = _shapes(one_chip, x, w, c)
         lower = lambda layer: _deconv2d_jit.lower(  # noqa: E731
             *args, *tiles, act, False, layer)
